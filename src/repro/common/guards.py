@@ -31,9 +31,9 @@ _listener_installed = False
 
 
 def jit_cache_size(fn) -> int:
-    """Compilation count of one jitted callable.  ``_cache_size`` is a
-    private jax API — degrade to 0 rather than break callers if it moves."""
-    return int(getattr(fn, "_cache_size", lambda: 0)())
+    """Compilation count of one jitted callable (``_cache_size`` of the
+    installed JAX's jit wrapper)."""
+    return int(fn._cache_size())
 
 
 def _install_listener() -> None:

@@ -49,12 +49,21 @@ def _init_leaf(decl: ParamDecl, key: jax.Array) -> jax.Array:
     return (decl.scale * jax.random.normal(key, decl.shape, jnp.float32)).astype(decl.dtype)
 
 
+# A leaf this large is drawn under jit: XLA fuses the fp32 normal draw into
+# the bf16 cast, so a full-width stacked leaf (24 x 3840 x 10240) never holds
+# its ~3.8 GB fp32 copy on the device.  Smaller leaves keep the eager draw.
+_JIT_INIT_ELEMS = 1 << 24
+_init_leaf_jit = jax.jit(_init_leaf, static_argnums=0)
+
+
 def init_params(decls, key: jax.Array):
     """Initialize a pytree of ParamDecl with per-leaf folded keys."""
     leaves, treedef = jax.tree.flatten(decls, is_leaf=is_decl)
     out = []
     for i, leaf in enumerate(leaves):
-        out.append(_init_leaf(leaf, jax.random.fold_in(key, i)))
+        init = (_init_leaf_jit if np.prod(leaf.shape) >= _JIT_INIT_ELEMS
+                else _init_leaf)
+        out.append(init(leaf, jax.random.fold_in(key, i)))
     return jax.tree.unflatten(treedef, out)
 
 

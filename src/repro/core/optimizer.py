@@ -511,17 +511,16 @@ def _blocked_window_fn(mesh, axes, *, mode: str, iters: int, lr_con: float,
                 jnp.asarray(step0, jnp.float32), nvf)
         if mesh is None:
             return core(*args)
-        from jax.experimental.shard_map import shard_map
         qspec = P(axes if len(axes) > 1 else axes[0])
         rep = P()
-        sharded = shard_map(
+        sharded = jax.shard_map(
             core, mesh=mesh,
             in_specs=(qspec, qspec, qspec, qspec) + (rep,) * 10,
             out_specs=(qspec, SolveInfo(*([rep] * 8)), rep, rep),
             # the while_loop's gathered reductions keep (λ, λ2) replicated
             # by construction; the static replication checker can't see
             # through the loop, so it is disabled rather than appeased
-            check_rep=False)
+            check_vma=False)
         return sharded(*args)
 
     return jax.jit(fn)

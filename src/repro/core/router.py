@@ -169,7 +169,6 @@ class OmniRouter(Policy):
 
         if mesh is None:
             return predict
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         qspec = P(axes if len(axes) > 1 else axes[0])
         rep = P()
@@ -177,8 +176,8 @@ class OmniRouter(Policy):
         def sharded(inputs, tokens, input_len, price_in, price_out):
             in_specs = (jax.tree_util.tree_map(lambda _: rep, inputs),
                         qspec, qspec, rep, rep)
-            return shard_map(predict, mesh=mesh, in_specs=in_specs,
-                             out_specs=(qspec, qspec), check_rep=False)(
+            return jax.shard_map(predict, mesh=mesh, in_specs=in_specs,
+                                 out_specs=(qspec, qspec), check_vma=False)(
                 inputs, tokens, input_len, price_in, price_out)
 
         return sharded

@@ -14,7 +14,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -61,9 +60,9 @@ def pipeline_forward(mesh: Mesh, stage_fn: Callable, n_microbatches: int):
         out = jax.lax.psum(jnp.where(sid == n_stages - 1, out, 0.0), "stage")
         return out
 
-    return shard_map(
+    return jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P("stage"), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )

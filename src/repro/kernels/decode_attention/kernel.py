@@ -33,34 +33,65 @@ NEG_INF = -1e30
 
 def _split_partials(q_ref, k_ref, v_ref, on_ref, m_ref, l_ref, *,
                     start, pos, t_valid: int, window: int, scale: float):
-    """Shared split body for both variants: one decode token against one KV
-    split starting at logical position ``start``, masked to
+    """Shared split body for every variant: the R query rows of each KV head
+    (R = G for decode, S·G for verify) against one KV split of BS positions
+    starting at logical position ``start``, masked to
     [max(pos - window, 0), min(pos, t_valid)), emitting the (o·l, m, l)
-    merge triple."""
-    q = q_ref[0, 0].astype(jnp.float32) * scale      # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)           # (BS, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, BS)
-    kv_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    # the zero-padded ragged tail (kv_pos >= t_valid) is NEG_INF-masked
-    # alongside the not-yet-written region (kv_pos >= pos)
-    valid = (kv_pos < pos) & (kv_pos < t_valid)
-    if window > 0:
-        valid &= kv_pos > pos - 1 - window
-    s = jnp.where(valid, s, NEG_INF)
-    m = s.max(axis=1)                                 # (G,)
-    p = jnp.exp(s - m[:, None])
-    l = p.sum(axis=1)
-    o = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
-    on_ref[0, 0, 0] = o.astype(on_ref.dtype)          # o·l numerator (G, D)
-    m_ref[0, 0, 0] = m.astype(m_ref.dtype)
-    l_ref[0, 0, 0] = l.astype(l_ref.dtype)
+    merge triple per head.
+
+    The K/V block holds ALL K heads of the split, (1, BS, K, D): a TPU block
+    must span its array's last two dims (K, D) whole, since K is below the
+    8-row sublane tile.  ``pos`` is a scalar or an (R, 1) column."""
+    for h in range(k_ref.shape[2]):
+        q = q_ref[0, h].astype(jnp.float32) * scale      # (R, D)
+        k = k_ref[0, :, h, :].astype(jnp.float32)        # (BS, D)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # (R, BS)
+        kv_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # the zero-padded ragged tail (kv_pos >= t_valid) is NEG_INF-masked
+        # alongside the not-yet-written region (kv_pos >= pos)
+        valid = (kv_pos < pos) & (kv_pos < t_valid)
+        if window > 0:
+            valid &= kv_pos > pos - 1 - window
+        s = jnp.where(valid, s, NEG_INF)
+        m = s.max(axis=1, keepdims=True)                 # (R, 1)
+        p = jnp.exp(s - m)
+        l = p.sum(axis=1, keepdims=True)
+        o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # (R, D)
+        on_ref[0, 0, h] = o.astype(on_ref.dtype)         # o·l numerator
+        m_ref[0, 0, h] = m.astype(m_ref.dtype)
+        l_ref[0, 0, h] = l.astype(l_ref.dtype)
+
+
+def _split_call(kernel, args, *, b: int, n_split: int, kh: int, r: int,
+                d: int, interpret: bool, **grid_kw):
+    """Run a split kernel (``grid_kw``: its grid and block specs) and return
+    its partials in the merge layout (o_num (B,K,S,R,D), m (B,K,S,R),
+    l (B,K,S,R)).  In-kernel the outputs are split-major, (B, S, K, R, ·),
+    so each block spans the (R, D) / (R, 1) tail of its array whole."""
+    out_shape = [
+        jax.ShapeDtypeStruct((b, n_split, kh, r, d), jnp.float32),
+        jax.ShapeDtypeStruct((b, n_split, kh, r, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b, n_split, kh, r, 1), jnp.float32),
+    ]
+    o, m, l = pl.pallas_call(kernel, out_shape=out_shape, interpret=interpret,
+                             **grid_kw)(*args)
+    o = jnp.swapaxes(o, 1, 2)
+    return o, jnp.swapaxes(m[..., 0], 1, 2), jnp.swapaxes(l[..., 0], 1, 2)
+
+
+def _out_specs(kh: int, r: int, d: int, index):
+    return [pl.BlockSpec((1, 1, kh, r, d), index),
+            pl.BlockSpec((1, 1, kh, r, 1), index),
+            pl.BlockSpec((1, 1, kh, r, 1), index)]
 
 
 def _kernel(q_ref, k_ref, v_ref, pos_ref, on_ref, m_ref, l_ref, *,
             bs: int, t_valid: int, window: int, scale: float):
     _split_partials(q_ref, k_ref, v_ref, on_ref, m_ref, l_ref,
-                    start=pl.program_id(2) * bs,
+                    start=pl.program_id(1) * bs,
                     pos=pos_ref[pl.program_id(0)],
                     t_valid=t_valid, window=window, scale=scale)
 
@@ -89,28 +120,16 @@ def decode_attention_kernel(q, k_cache, v_cache, pos, *, window: int = 0,
 
     kernel = functools.partial(_kernel, bs=bs, t_valid=t, window=window,
                                scale=d ** -0.5)
-    o, m, l = pl.pallas_call(
-        kernel,
-        grid=(b, kh, ns),
+    return _split_call(
+        kernel, (qT, k_cache, v_cache, pos_arr), b=b, n_split=ns, kh=kh,
+        r=g, d=d, interpret=interpret, grid=(b, ns),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda b_, k_, s_: (b_, k_, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d), lambda b_, k_, s_: (b_, s_, k_, 0)),
-            pl.BlockSpec((1, bs, 1, d), lambda b_, k_, s_: (b_, s_, k_, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, kh, g, d), lambda b_, s_: (b_, 0, 0, 0)),
+            pl.BlockSpec((1, bs, kh, d), lambda b_, s_: (b_, s_, 0, 0)),
+            pl.BlockSpec((1, bs, kh, d), lambda b_, s_: (b_, s_, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, g, d), lambda b_, k_, s_: (b_, k_, s_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, g), lambda b_, k_, s_: (b_, k_, s_, 0)),
-            pl.BlockSpec((1, 1, 1, g), lambda b_, k_, s_: (b_, k_, s_, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kh, ns, g, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, ns, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, ns, g), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qT, k_cache, v_cache, pos_arr)
-    return o, m, l
+        out_specs=_out_specs(kh, g, d, lambda b_, s_: (b_, s_, 0, 0, 0)))
 
 
 def _paged_verify_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, on_ref, m_ref,
@@ -123,8 +142,45 @@ def _paged_verify_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, on_ref, m_ref,
     rows = jax.lax.broadcasted_iota(jnp.int32, (q_ref.shape[2], 1), 0)
     pos = len_ref[pl.program_id(0)] + rows // g
     _split_partials(q_ref, k_ref, v_ref, on_ref, m_ref, l_ref,
-                    start=pl.program_id(2) * ps, pos=pos,
+                    start=pl.program_id(1) * ps, pos=pos,
                     t_valid=p_max * ps, window=window, scale=scale)
+
+
+def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, on_ref, m_ref, l_ref,
+                  *, ps: int, p_max: int, window: int, scale: float):
+    # the k/v blocks hold the physical page bt_ref[b, s]; logically it spans
+    # positions [s·ps, (s+1)·ps) of sequence b, masked against lens[b]
+    _split_partials(q_ref, k_ref, v_ref, on_ref, m_ref, l_ref,
+                    start=pl.program_id(1) * ps,
+                    pos=len_ref[pl.program_id(0)],
+                    t_valid=p_max * ps, window=window, scale=scale)
+
+
+def _paged_call(kernel, qT, k_pages, v_pages, block_table, lens, *,
+                interpret: bool):
+    """Shared launch of the paged decode/verify kernels: grid (B, P), the
+    block table and lens on scalar prefetch, and page indirection in the
+    K/V index maps — the pool is never gathered into a dense copy."""
+    b, kh, r, d = qT.shape
+    ps = k_pages.shape[1]
+    p_max = block_table.shape[1]
+    page = lambda b_, s_, bt_, ln_: (bt_[b_, s_], 0, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                       # (block_table, lens)
+        grid=(b, p_max),
+        in_specs=[
+            pl.BlockSpec((1, kh, r, d), lambda b_, s_, bt_, ln_: (b_, 0, 0, 0)),
+            pl.BlockSpec((1, ps, kh, d), page),
+            pl.BlockSpec((1, ps, kh, d), page),
+        ],
+        out_specs=_out_specs(kh, r, d,
+                             lambda b_, s_, bt_, ln_: (b_, s_, 0, 0, 0)),
+    )
+    return _split_call(kernel, (jnp.asarray(block_table, jnp.int32),
+                                jnp.asarray(lens, jnp.int32), qT, k_pages,
+                                v_pages),
+                       b=b, n_split=p_max, kh=kh, r=r, d=d,
+                       interpret=interpret, grid_spec=grid_spec)
 
 
 def paged_verify_attention_kernel(q, k_pages, v_pages, block_table, lens, *,
@@ -132,7 +188,7 @@ def paged_verify_attention_kernel(q, k_pages, v_pages, block_table, lens, *,
     """Speculative-verify twin of ``paged_decode_attention_kernel``:
     q is (B,S,H,D) — S query positions per sequence, query s of sequence b
     masked to positions < lens[b] + s.  The S axis rides the q block's row
-    axis (S·G rows per (b, k) program), so the grid and the block-table
+    axis (S·G rows per KV head), so the grid and the block-table
     scalar-prefetch indirection are identical to the decode kernel.
 
     Returns partials (o_num (B,K,P,S·G,D), m (B,K,P,S·G), l (B,K,P,S·G)).
@@ -141,57 +197,15 @@ def paged_verify_attention_kernel(q, k_pages, v_pages, block_table, lens, *,
     ps, kh = k_pages.shape[1], k_pages.shape[2]
     g = h // kh
     p_max = block_table.shape[1]
-    sg = s_q * g
 
-    # (B,S,H,D) -> (B, K, S·G, D): row r of program (b, k) is query
-    # position r // g, query-group r % g
+    # (B,S,H,D) -> (B, K, S·G, D): row r of head k is query position r // g,
+    # query-group r % g
     qT = q.reshape(b, s_q, kh, g, d).transpose(0, 2, 1, 3, 4).reshape(
-        b, kh, sg, d)
-    bt = jnp.asarray(block_table, jnp.int32)
-    lens = jnp.asarray(lens, jnp.int32)
-
+        b, kh, s_q * g, d)
     kernel = functools.partial(_paged_verify_kernel, ps=ps, p_max=p_max,
                                g=g, window=window, scale=d ** -0.5)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                       # (block_table, lens)
-        grid=(b, kh, p_max),
-        in_specs=[
-            pl.BlockSpec((1, 1, sg, d), lambda b_, k_, s_, bt_, ln_: (b_, k_, 0, 0)),
-            pl.BlockSpec((1, ps, 1, d),
-                         lambda b_, k_, s_, bt_, ln_: (bt_[b_, s_], 0, k_, 0)),
-            pl.BlockSpec((1, ps, 1, d),
-                         lambda b_, k_, s_, bt_, ln_: (bt_[b_, s_], 0, k_, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, sg, d),
-                         lambda b_, k_, s_, bt_, ln_: (b_, k_, s_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, sg),
-                         lambda b_, k_, s_, bt_, ln_: (b_, k_, s_, 0)),
-            pl.BlockSpec((1, 1, 1, sg),
-                         lambda b_, k_, s_, bt_, ln_: (b_, k_, s_, 0)),
-        ],
-    )
-    o, m, l = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kh, p_max, sg, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, p_max, sg), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, p_max, sg), jnp.float32),
-        ],
-        interpret=interpret,
-    )(bt, lens, qT, k_pages, v_pages)
-    return o, m, l
-
-
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, on_ref, m_ref, l_ref,
-                  *, ps: int, p_max: int, window: int, scale: float):
-    # the k/v blocks hold the physical page bt_ref[b, s]; logically it spans
-    # positions [s·ps, (s+1)·ps) of sequence b, masked against lens[b]
-    _split_partials(q_ref, k_ref, v_ref, on_ref, m_ref, l_ref,
-                    start=pl.program_id(2) * ps,
-                    pos=len_ref[pl.program_id(0)],
-                    t_valid=p_max * ps, window=window, scale=scale)
+    return _paged_call(kernel, qT, k_pages, v_pages, block_table, lens,
+                       interpret=interpret)
 
 
 def paged_decode_attention_kernel(q, k_pages, v_pages, block_table, lens, *,
@@ -206,44 +220,8 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table, lens, *,
     """
     b, _, h, d = q.shape
     ps, kh = k_pages.shape[1], k_pages.shape[2]
-    g = h // kh
     p_max = block_table.shape[1]
-
-    qT = q.reshape(b, kh, g, d)
-    bt = jnp.asarray(block_table, jnp.int32)
-    lens = jnp.asarray(lens, jnp.int32)
-
     kernel = functools.partial(_paged_kernel, ps=ps, p_max=p_max,
                                window=window, scale=d ** -0.5)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                       # (block_table, lens)
-        grid=(b, kh, p_max),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda b_, k_, s_, bt_, ln_: (b_, k_, 0, 0)),
-            # page indirection: the physical page id comes from the prefetched
-            # block table — the pool is never gathered into a dense copy
-            pl.BlockSpec((1, ps, 1, d),
-                         lambda b_, k_, s_, bt_, ln_: (bt_[b_, s_], 0, k_, 0)),
-            pl.BlockSpec((1, ps, 1, d),
-                         lambda b_, k_, s_, bt_, ln_: (bt_[b_, s_], 0, k_, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, g, d),
-                         lambda b_, k_, s_, bt_, ln_: (b_, k_, s_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, g),
-                         lambda b_, k_, s_, bt_, ln_: (b_, k_, s_, 0)),
-            pl.BlockSpec((1, 1, 1, g),
-                         lambda b_, k_, s_, bt_, ln_: (b_, k_, s_, 0)),
-        ],
-    )
-    o, m, l = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kh, p_max, g, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, p_max, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, p_max, g), jnp.float32),
-        ],
-        interpret=interpret,
-    )(bt, lens, qT, k_pages, v_pages)
-    return o, m, l
+    return _paged_call(kernel, q.reshape(b, kh, h // kh, d), k_pages, v_pages,
+                       block_table, lens, interpret=interpret)

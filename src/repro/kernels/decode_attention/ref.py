@@ -57,23 +57,16 @@ def verify_attention_ref(q, k_cache, v_cache, lens, *, window: int = 0):
     sequence, where query s of sequence b sits at cache position
     ``lens[b] - 1 + s`` and attends to positions < ``lens[b] + s`` (its own
     K/V is already written, exactly like the decode path's ``pos + 1``
-    convention).  fp32 softmax."""
-    b, s_q, h, d = q.shape
-    t, kh = k_cache.shape[1], k_cache.shape[2]
-    g = h // kh
-    qf = q.reshape(b, s_q, kh, g, d).astype(jnp.float32) * (d ** -0.5)
-    s = jnp.einsum("bskgd,btkd->bskgt", qf, k_cache.astype(jnp.float32))
-    kv = jnp.arange(t)
-    # per-position valid lengths: (B, S, 1)
-    pcol = _lens_col(lens)[:, :, None] + jnp.arange(s_q)[None, :, None]
-    valid = kv[None, None, :] < pcol
-    if window > 0:
-        valid = valid & (kv[None, None, :] > pcol - 1 - window)
-    s = jnp.where(valid[:, :, None, None, :], s, -1e30)
-    p = jnp.exp(s - s.max(-1, keepdims=True))
-    p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
-    o = jnp.einsum("bskgt,btkd->bskgd", p, v_cache.astype(jnp.float32))
-    return o.reshape(b, s_q, h, d).astype(q.dtype)
+    convention).  fp32 softmax.
+
+    Built as S decode references, one per position: slice s IS
+    ``decode_attention_ref`` at ``lens + s``, bit for bit, whatever
+    reduction order the backend picks for an S-batched einsum."""
+    lens = jnp.asarray(lens, jnp.int32)
+    return jnp.concatenate(
+        [decode_attention_ref(q[:, j:j + 1], k_cache, v_cache, lens + j,
+                              window=window) for j in range(q.shape[1])],
+        axis=1)
 
 
 def paged_verify_attention_ref(q, k_pages, v_pages, block_table, lens, *,

@@ -51,15 +51,14 @@ def solve_fused(cost, quality, threshold, loads, *, mode: str = "quality",
          a_bar, b_bar) = _normalize_problem(
             a_mat, b_mat, t_eff, lr_con, lr_load, lam0, lam20, loads)
 
-    out, nb = fused_dual_solve(
+    out, vec, nb = fused_dual_solve(
         a_mat, b_mat, t_eff, loads, iters=iters, lr_eff=lr_eff,
         lr_load=lr_load, bq=bq, lam0=lam0, lam20=lam20,
         stall_tol=stall_tol, step0=step0, patience=patience,
         interpret=interpret)
     lam, lam_b, best_obj, found_f, asum, bsum = (
         out[0], out[1], out[2], out[3], out[4], out[5])
-    lam2 = out[8:8 + m]
-    lam2b = out[8 + m:8 + 2 * m]
+    lam2, lam2b, cnt = vec[0], vec[1], vec[2]
 
     if nb == 1:
         # single-block kernel: every iteration (incl. the last) is finalized
@@ -69,7 +68,6 @@ def solve_fused(cost, quality, threshold, loads, *, mode: str = "quality",
         found = found_f > 0.0
         iters_run = out[6].astype(jnp.int32)
     else:
-        cnt = out[8 + 2 * m:8 + 3 * m]
         # finalize the last iteration (the grid kernel finalizes iteration
         # t-1 at the start of iteration t, so iters-1 is finalized here) —
         # unless the solve froze (early exit), in which case the reference

@@ -41,25 +41,40 @@ def _fold_topk(v_scr, i_scr, sims, col, k: int):
 
     Candidate indices are pairwise distinct (previous picks hold columns from
     earlier tiles; ``col`` covers this tile), so k rounds of extract-max give
-    the exact running top-k.  Ties resolve to the earlier concat position =
-    the lower db index, matching ``jax.lax.top_k``.  Exhausted rounds (all
-    remaining candidates at NEG_INF) record index -1, never a real row.
+    the exact running top-k.  Each round picks the LOWEST db index holding
+    the maximum, matching ``jax.lax.top_k``'s tie order.  Exhausted rounds
+    (all remaining candidates at NEG_INF) record index -1, never a real row.
+
+    Everything is a lane reduction or an iota compare-and-select: Mosaic
+    lowers no in-kernel gather or scatter.  Indices ride in f32 (exact below
+    2**24 rows) because the lane min-reduction is a float op.
     """
-    cur_v = jnp.concatenate([v_scr[...], sims], axis=1)      # (BQ, k+TILE)
-    cur_i = jnp.concatenate([i_scr[...], col], axis=1)
-    rows = jnp.arange(cur_v.shape[0])
+    prev_v = v_scr[...]                                      # (BQ, k)
+    prev_i = i_scr[...].astype(jnp.float32)
+    colf = col.astype(jnp.float32)                           # (BQ, TILE)
+    slot = jax.lax.broadcasted_iota(jnp.int32, prev_v.shape, 1)
+    big = jnp.float32(2 ** 30)
+    new_v = jnp.full(prev_v.shape, NEG_INF, jnp.float32)
+    new_i = jnp.full(prev_v.shape, -1.0, jnp.float32)
     for r in range(k):
-        m = cur_v.max(axis=1)
-        am = cur_v.argmax(axis=1)
-        picked = jnp.take_along_axis(cur_i, am[:, None], axis=1)[:, 0]
-        v_scr[:, r] = m
-        i_scr[:, r] = jnp.where(m > NEG_INF * 0.5, picked, -1)
-        cur_v = cur_v.at[rows, am].set(NEG_INF)
+        m = jnp.maximum(prev_v.max(axis=1, keepdims=True),
+                        sims.max(axis=1, keepdims=True))     # (BQ, 1)
+        picked = jnp.minimum(
+            jnp.where(prev_v == m, prev_i, big).min(axis=1, keepdims=True),
+            jnp.where(sims == m, colf, big).min(axis=1, keepdims=True))
+        picked = jnp.where(m > NEG_INF * 0.5, picked, -1.0)
+        new_v = jnp.where(slot == r, m, new_v)
+        new_i = jnp.where(slot == r, picked, new_i)
+        prev_v = jnp.where(prev_i == picked, NEG_INF, prev_v)
+        sims = jnp.where(colf == picked, NEG_INF, sims)
+    v_scr[...] = new_v
+    i_scr[...] = new_i.astype(jnp.int32)
 
 
 def _masked_sims(q_ref, db_ref, nv_ref, it, tile: int):
     """(BQ, TILE) similarity block with db rows >= n_valid masked out."""
     sims = jax.lax.dot_general(q_ref[...], db_ref[...], (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
     col = it * tile + jax.lax.broadcasted_iota(jnp.int32, sims.shape, 1)
     return jnp.where(col < nv_ref[0], sims, NEG_INF), col
@@ -115,6 +130,7 @@ def _vote_kernel(nv_ref, q_ref, db_ref, lab_ref, vals_ref, idx_ref, vote_ref,
             member += (col == idxs[:, r:r + 1]).astype(jnp.float32)
         acc_scr[...] += jax.lax.dot_general(
             member, lab_ref[...], (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
         @pl.when(it == n_tiles - 1)

@@ -20,7 +20,11 @@ from .kernel import NEG_INF
 
 
 def _masked_sims(store, queries, n_valid):
-    sims = queries.astype(jnp.float32) @ store.astype(jnp.float32).T
+    # full-f32 products on every backend (a TPU's default f32 matmul rounds
+    # operands to bf16): the kernel uses the same precision, so near-tied
+    # neighbours rank alike on both paths
+    sims = jnp.matmul(queries.astype(jnp.float32), store.astype(jnp.float32).T,
+                      precision=jax.lax.Precision.HIGHEST)
     if n_valid is not None:
         col = jax.lax.broadcasted_iota(jnp.int32, sims.shape, 1)
         sims = jnp.where(col < n_valid, sims, NEG_INF)

@@ -8,21 +8,61 @@ of all at once, and the router can run as a persistent dual controller —
   PYTHONPATH=src python -m repro.launch.serve --arrival poisson \
       --arrival-rate 4 --stream
 
-The same server binds full configs to per-arch submeshes on hardware; the
-dry-run proves every (arch x decode shape) lowers on the production mesh.
+``build_server`` is the one place the pool and server are assembled; the
+launcher and ``chip_smoke.py`` (the full-width endpoint on a TPU) both call
+it.  ``use_compile_cache`` places JAX's persistent compilation cache.
 """
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from typing import Sequence
 
+import jax
 import numpy as np
 
 from repro.configs import get_smoke_config
+from repro.configs.base import ModelConfig
 from repro.core import (OmniRouter, RetrievalPredictor, RouterConfig)
 from repro.data import arrivals, tokenizer
 from repro.data.qaserve import generate
 from repro.serving.engine import Endpoint, MultiLLMServer, Request
+
+# one pool member per QAServe fleet column (data/qaserve.py DEFAULT_POOL)
+POOL_ARCHS = ("h2o-danube-3-4b", "internlm2-20b", "qwen2-72b",
+              "gemma3-4b", "hymba-1.5b", "xlstm-350m")
+
+# <checkout>/.jax_cache — git-ignored, fixed, so a later run finds it again
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
+    nothing is set here.  Otherwise the cache goes to ``DEFAULT_CACHE_DIR``,
+    a fixed path — the path is part of what a later run must match to hit."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR
+
+
+def build_server(cfgs: Sequence[ModelConfig], router, *,
+                 max_concurrency: int = 4, t_max: int = 128,
+                 batch_size: int = 0, stream: bool = False,
+                 horizon: int = 0) -> MultiLLMServer:
+    """One paged :class:`Endpoint` per config (endpoint ``i`` serves router
+    column ``i``, seeded ``i``) behind ``router`` in a
+    :class:`MultiLLMServer`."""
+    endpoints = [Endpoint(cfg, max_concurrency=max_concurrency, t_max=t_max,
+                          seed=i) for i, cfg in enumerate(cfgs)]
+    return MultiLLMServer(endpoints, router, batch_size=batch_size,
+                          stream=stream, horizon=horizon)
 
 
 def main(argv=None):
@@ -40,6 +80,7 @@ def main(argv=None):
                     help="persistent dual controller: warm-started windows, "
                          "cumulative budget/alpha ledger")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     ds = generate(n=600, seed=0)
     train, _, test = ds.split()
@@ -47,14 +88,11 @@ def main(argv=None):
 
     router = OmniRouter(RetrievalPredictor(k=8).fit(train),
                         RouterConfig(alpha=args.alpha), name="ECCOS-R")
-
-    pool_archs = ["h2o-danube-3-4b", "internlm2-20b", "qwen2-72b",
-                  "gemma3-4b", "hymba-1.5b", "xlstm-350m"]
-    endpoints = [Endpoint(get_smoke_config(a), max_concurrency=args.loads,
-                          seed=i) for i, a in enumerate(pool_archs)]
-    server = MultiLLMServer(endpoints, router,
-                            batch_size=1 if args.mode == "streaming" else 0,
-                            stream=args.stream, horizon=test.n)
+    server = build_server([get_smoke_config(a) for a in POOL_ARCHS], router,
+                          max_concurrency=args.loads,
+                          batch_size=1 if args.mode == "streaming" else 0,
+                          stream=args.stream, horizon=test.n)
+    endpoints = server.endpoints
 
     # remap router tokens into the pool's (smoke-sized) model vocab — the
     # shared helper replaces the old hardcoded `toks % 500` at call sites
@@ -82,7 +120,7 @@ def main(argv=None):
           + (f", {server.dual_iters} dual iters" if args.stream else ""))
     for j, e in enumerate(endpoints):
         n_j = int((assign == j).sum())
-        print(f"  endpoint {j} ({pool_archs[j]}): {n_j} reqs, "
+        print(f"  endpoint {j} ({POOL_ARCHS[j]}): {n_j} reqs, "
               f"{e.decoded_tokens} tokens in {e.busy_steps} decode chunks, "
               f"{e.compile_count()} compiles, "
               f"{e.batch_reprefills} batch re-prefills")

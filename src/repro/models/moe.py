@@ -18,7 +18,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.common import ParamDecl, active_mesh, logical_shard
 from repro.configs.base import ModelConfig
@@ -161,7 +160,7 @@ def moe_ep(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
     body = lambda xt_, rw, wg, wu, wd: _moe_local(
         cfg, xt_, rw, wg, wu, wd, n_dest=n_dest, axis_data="data", axis_model="model"
     )
-    y = shard_map(
+    y = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -172,7 +171,7 @@ def moe_ep(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
             P("data", "model", None),
         ),
         out_specs=P(dp, None),
-        check_rep=False,
+        check_vma=False,
     )(xt, params["router"], params["w_gate"], params["w_up"], params["w_down"])
     return y.reshape(b, s, d)
 

@@ -224,9 +224,10 @@ def test_sharded_solver_bit_parity_8dev():
 def test_sharded_route_window_stream_parity_8dev():
     """End-to-end predict->solve under the mesh: non-divisible windows
     (37/53/30) pad to shard-divisible buckets, assignments are bit-equal to
-    the single-device stream, and the ledger matches to float tolerance
-    (the encoder matmuls retile across local sizes, so the ledger's λ is
-    allowed 1-ulp drift while the integer/accumulated fields stay exact)."""
+    the single-device stream, and the ledger matches to float tolerance.
+    The predictor's matmuls retile across local batch sizes, so predicted
+    quality — and with it the quality ledger and λ — may drift by a few
+    ulps; the dual-step count and the budget ledger stay exact."""
     print(_run("""
         import numpy as np, jax
         assert jax.device_count() == 8
@@ -268,9 +269,11 @@ def test_sharded_route_window_stream_parity_8dev():
         for (i0, sz), a, b in zip(windows, x_m, x_s):
             assert len(a) == sz                       # padding sliced off
             assert np.array_equal(a, b), (i0, sz)
-        for f in ("budget_spent", "sr_deficit", "steps"):
+        for f in ("budget_spent", "steps"):
             assert np.array_equal(np.asarray(getattr(st_m, f)),
                                   np.asarray(getattr(st_s, f))), f
+        assert np.allclose(np.asarray(st_m.sr_deficit),
+                           np.asarray(st_s.sr_deficit), rtol=1e-5, atol=0)
         for f in ("lam", "lam_load"):
             assert np.allclose(np.asarray(getattr(st_m, f)),
                                np.asarray(getattr(st_s, f)),
