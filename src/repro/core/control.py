@@ -34,19 +34,21 @@ The executor duck-type:
                                        new can happen (arrival / window
                                        deadline) for idle clock jumps
     tick()                             post-event hook (hedging)
+    on_release(items)                  items just released into the ready
+                                       queue (the engine stamps the time)
 """
 from __future__ import annotations
 
 import dataclasses
 import heapq
 import itertools
-import time
 from collections import deque
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
 from repro.analysis import sanitize as _sanitize
+from repro.common import tracing
 
 from .baselines import Policy, pad_batch, pad_bucket
 from .optimizer import DualState
@@ -140,7 +142,6 @@ class StreamController:
         self.state: Optional[DualState] = None
         self.routed = 0
         self.windows = 0
-        self.route_seconds = 0.0
         self._iters0 = int(getattr(policy, "dual_iters", 0))
 
     def route(self, ds_like, loads, counts) -> np.ndarray:
@@ -163,56 +164,55 @@ class StreamController:
         the dual controller itself — it routes against ``batch.available``
         and ``repair_workload`` enforces it exactly — but a custom
         stateful policy that over-commits capacity would drift."""
-        t0 = time.perf_counter()
-        if self.health is not None:
-            # breakers fold into the workload constraint (OPEN -> capacity
-            # 0, HALF_OPEN -> probe slots), so the solver simply can't
-            # assign to a tripped endpoint; latency EWMAs reprice the cost
-            # column (multiplier >= 1: the ledger only over-estimates).
-            loads = self.health.effective_loads(loads)
-        if self.stream:
-            batch = ds_like.route_batch(
-                np.asarray(loads, float), counts,
-                with_truth=getattr(self.policy, "needs_truth", False))
+        with tracing.span("route"):
             if self.health is not None:
-                pm = self.health.price_multiplier()
-                if np.any(pm != 1.0):
-                    batch = dataclasses.replace(
-                        batch,
-                        price_in=(batch.price_in * pm).astype(
-                            batch.price_in.dtype),
-                        price_out=(batch.price_out * pm).astype(
-                            batch.price_out.dtype))
-            n_true = batch.n
-            n_rem = max(self.horizon - self.routed, n_true)
-            state_in = self.state
-            if getattr(self.policy, "pads_windows", False):
-                mult = getattr(self.policy, "window_multiple",
-                               lambda: 1)()
-                batch = pad_batch(batch, pad_bucket(n_true, mult))
-                x, self.state = self.policy.route_window(
-                    batch, self.state, share=n_true / n_rem, rng=self.rng,
-                    n_valid=n_true)
-                x = np.asarray(x)[:n_true]
+                # breakers fold into the workload constraint (OPEN -> capacity
+                # 0, HALF_OPEN -> probe slots), so the solver simply can't
+                # assign to a tripped endpoint; latency EWMAs reprice the cost
+                # column (multiplier >= 1: the ledger only over-estimates).
+                loads = self.health.effective_loads(loads)
+            if self.stream:
+                batch = ds_like.route_batch(
+                    np.asarray(loads, float), counts,
+                    with_truth=getattr(self.policy, "needs_truth", False))
+                if self.health is not None:
+                    pm = self.health.price_multiplier()
+                    if np.any(pm != 1.0):
+                        batch = dataclasses.replace(
+                            batch,
+                            price_in=(batch.price_in * pm).astype(
+                                batch.price_in.dtype),
+                            price_out=(batch.price_out * pm).astype(
+                                batch.price_out.dtype))
+                n_true = batch.n
+                n_rem = max(self.horizon - self.routed, n_true)
+                state_in = self.state
+                if getattr(self.policy, "pads_windows", False):
+                    mult = getattr(self.policy, "window_multiple",
+                                   lambda: 1)()
+                    batch = pad_batch(batch, pad_bucket(n_true, mult))
+                    x, self.state = self.policy.route_window(
+                        batch, self.state, share=n_true / n_rem, rng=self.rng,
+                        n_valid=n_true)
+                    x = np.asarray(x)[:n_true]
+                else:
+                    x, self.state = self.policy.route_window(
+                        batch, self.state, share=n_true / n_rem, rng=self.rng)
+                if (_sanitize.active("ledgersan") and state_in is not None
+                        and self.state is not None):
+                    # host-level ledger monotonicity across the window — covers
+                    # the fused predict->solve path the solver-level hook must
+                    # skip (everything is a tracer inside the jit)
+                    _sanitize.check_state_monotone(state_in, self.state,
+                                                   where="StreamController")
+                n_routed = n_true
             else:
-                x, self.state = self.policy.route_window(
-                    batch, self.state, share=n_true / n_rem, rng=self.rng)
-            if (_sanitize.active("ledgersan") and state_in is not None
-                    and self.state is not None):
-                # host-level ledger monotonicity across the window — covers
-                # the fused predict->solve path the solver-level hook must
-                # skip (everything is a tracer inside the jit)
-                _sanitize.check_state_monotone(state_in, self.state,
-                                               where="StreamController")
-            n_routed = n_true
-        else:
-            from .scheduler import route_via_batch
-            x = route_via_batch(self.policy, ds_like, loads, counts,
-                                rng=self.rng)
-            n_routed = len(x)
-        self.route_seconds += time.perf_counter() - t0
-        self.routed += n_routed
-        self.windows += 1
+                from .scheduler import route_via_batch
+                x = route_via_batch(self.policy, ds_like, loads, counts,
+                                    rng=self.rng)
+                n_routed = len(x)
+            self.routed += n_routed
+            self.windows += 1
         return np.asarray(x).astype(int)
 
     @property
@@ -236,7 +236,6 @@ class FoldBuffer:
         self.chunk = max(1, chunk)
         self.buf: List = []
         self.folded = 0
-        self.fold_seconds = 0.0
 
     def add(self, items: Sequence):
         if self.enabled:
@@ -248,11 +247,10 @@ class FoldBuffer:
         if not force and len(self.buf) < self.chunk:
             return
         from .scheduler import fold_completions
-        t0 = time.perf_counter()
-        if fold_completions(self.policy, self.features(self.buf),
-                            np.arange(len(self.buf))):
-            self.folded += len(self.buf)
-        self.fold_seconds += time.perf_counter() - t0
+        with tracing.span("fold"):
+            if fold_completions(self.policy, self.features(self.buf),
+                                np.arange(len(self.buf))):
+                self.folded += len(self.buf)
         self.buf.clear()
 
 
@@ -323,9 +321,14 @@ class ControlLoop:
 
     # -- stream bookkeeping ----------------------------------------------------
     def _release_arrivals(self):
-        now = self.executor.now()
-        while self.pending and self.pending[0][0] <= now + 1e-9:
-            self.ready.append(heapq.heappop(self.pending)[2])
+        with tracing.span("loop.release"):
+            now = self.executor.now()
+            released = []
+            while self.pending and self.pending[0][0] <= now + 1e-9:
+                released.append(heapq.heappop(self.pending)[2])
+            if released:
+                self.ready.extend(released)
+                self.executor.on_release(released)
 
     def _wake_at(self) -> Optional[float]:
         """Next clock value at which something new can happen while the
@@ -391,20 +394,21 @@ class ControlLoop:
         ex = self.executor
         self._release_arrivals()
         while self.ready or self.pending or ex.counts().sum() > 0:
-            if getattr(ex, "stopped", False):
-                break               # executor hit its hard step budget
-            if self.health is not None:
-                self.health.advance(ex.now())   # OPEN -> HALF_OPEN on expiry
-            admitted = self._try_admit()
-            if admitted and self.drain_admissions:
-                continue
-            done, progressed = ex.advance(self._wake_at())
-            if done:
-                self.fold.add(done)
-                self.fold.flush()
-            ex.tick()
-            self._release_arrivals()
-            if not progressed and not admitted:
-                break               # deadlocked or out of steps: bail
+            with tracing.span("loop.iter"):
+                if getattr(ex, "stopped", False):
+                    break           # executor hit its hard step budget
+                if self.health is not None:
+                    self.health.advance(ex.now())  # OPEN -> HALF_OPEN
+                admitted = self._try_admit()
+                if admitted and self.drain_admissions:
+                    continue
+                done, progressed = ex.advance(self._wake_at())
+                if done:
+                    self.fold.add(done)
+                    self.fold.flush()
+                ex.tick()
+                self._release_arrivals()
+                if not progressed and not admitted:
+                    break           # deadlocked or out of steps: bail
         self.fold.flush(force=True)
         return self

@@ -453,8 +453,9 @@ def _blocked_window_core(a_mat, b_mat, cost, quality, t_eff, p_eff, loads,
             # each shard polishes toward its valid-row share of the budget
             return budget_polish(x1, c2, q2, share_s, quota, nv_s)
 
-        x2 = jax.lax.map(lambda t: one_polish(*t),
-                         (x2, c3, q3, quotas, nv_loc, shares))
+        with jax.named_scope("repair"):
+            x2 = jax.lax.map(lambda t: one_polish(*t),
+                             (x2, c3, q3, quotas, nv_loc, shares))
     csum = chosen(c3, x2)
     qsum = chosen(q3, x2)
     return x2.reshape(nloc), info, csum, qsum
@@ -702,14 +703,16 @@ class DualSolver:
         quality = jnp.asarray(quality, jnp.float32)
         loads = jnp.asarray(loads, jnp.float32)
         lam1 = info.lam if self.mode == "quality" else jnp.zeros(())
-        x = repair_workload(x, cost, quality, loads, lam1=lam1)
-        if self.mode == "quality":
-            pt = threshold if polish_threshold is None else polish_threshold
-            x = primal_polish(x, cost, quality,
-                              jnp.asarray(pt, jnp.float32), loads)
-        else:
-            x = budget_polish(x, cost, quality,
-                              jnp.asarray(threshold, jnp.float32), loads)
+        with jax.named_scope("repair"):
+            x = repair_workload(x, cost, quality, loads, lam1=lam1)
+            if self.mode == "quality":
+                pt = (threshold if polish_threshold is None
+                      else polish_threshold)
+                x = primal_polish(x, cost, quality,
+                                  jnp.asarray(pt, jnp.float32), loads)
+            else:
+                x = budget_polish(x, cost, quality,
+                                  jnp.asarray(threshold, jnp.float32), loads)
         return x, info
 
     def route_window(self, cost, quality, threshold, loads,

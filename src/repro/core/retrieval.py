@@ -100,9 +100,12 @@ def retrieval_predict_device(store_emb, store_labels, n_valid, proj, tokens,
     """
     from repro.kernels.topk_retrieval.ops import retrieval_vote
 
-    q = featurize_tokens(tokens, proj)
-    vals, idx, votes = retrieval_vote(store_emb, store_labels, q, k,
-                                      n_valid=n_valid, use_kernel=use_kernel)
+    with jax.named_scope("featurize"):
+        q = featurize_tokens(tokens, proj)
+    with jax.named_scope("vote"):
+        vals, idx, votes = retrieval_vote(store_emb, store_labels, q, k,
+                                          n_valid=n_valid,
+                                          use_kernel=use_kernel)
     m = price_in.shape[0]
     cap, exp_len = votes[:, :m], votes[:, m:]
     cost = predicted_cost(input_len, exp_len, price_in, price_out)

@@ -26,7 +26,6 @@ stream state passed as arrays.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, Optional
 
 import jax
@@ -34,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis import sanitize as _sanitize
+from repro.common import tracing
 from repro.data.qaserve import QAServe
 from repro.data import tokenizer
 from .baselines import Policy, RouteBatch
@@ -108,8 +108,6 @@ class OmniRouter(Policy):
         self.pairs = tuple(cfg.spec_pairs)
         self.acceptance = (AcceptanceTracker(self.pairs) if self.pairs
                            else None)
-        self.route_seconds = 0.0    # scheduling-overhead accounting (Fig. 3)
-        self.predict_seconds = 0.0
         self._dual_iters = 0        # synced portion of the iteration count
         self._iters_pending: list = []  # device scalars awaiting one batch sync
         self.windows = 0            # streaming windows routed
@@ -203,8 +201,9 @@ class OmniRouter(Policy):
                   threshold, polish_threshold):
             cap, cost = predict(inputs, tokens, input_len, price_in,
                                 price_out)
-            return solver.route_arrays(cost, cap, threshold, avail,
-                                       polish_threshold=polish_threshold)
+            with jax.named_scope("solve"):
+                return solver.route_arrays(cost, cap, threshold, avail,
+                                           polish_threshold=polish_threshold)
 
         return jax.jit(fused)
 
@@ -224,9 +223,10 @@ class OmniRouter(Policy):
                 # the jit boundary: the acceptance EWMA is a runtime array,
                 # so repricing never retraces
                 cost, cap = expand_pair_columns(cost, cap, didx, vidx, e_acc)
-            return solver.route_window(cost, cap, threshold, avail, state,
-                                       share=share, polish_margin=margin,
-                                       n_valid=n_valid)
+            with jax.named_scope("solve"):
+                return solver.route_window(cost, cap, threshold, avail, state,
+                                           share=share, polish_margin=margin,
+                                           n_valid=n_valid)
 
         # jit signatures are positional: fix one per (pairs?, masked?) so
         # optional args never shift position between calls
@@ -286,38 +286,37 @@ class OmniRouter(Policy):
         e_acc = (jnp.asarray(self.acceptance.expected(), jnp.float32)
                  if self.pairs else None)
         if hasattr(self.predictor, "predict_device"):
-            t0 = time.perf_counter()
-            toks = jnp.asarray(tokenizer.encode_batch(
-                batch.queries, self.predictor.token_len))
-            t1 = time.perf_counter()
-            self.predict_seconds += t1 - t0
-            fn = self._fused_fn("window", masked=n_valid is not None)
-            args = [self.predictor.device_inputs(), toks,
-                    jnp.asarray(batch.input_len, jnp.float32),
-                    jnp.asarray(batch.price_in, jnp.float32),
-                    jnp.asarray(batch.price_out, jnp.float32),
-                    jnp.asarray(batch.available, jnp.float32),
-                    jnp.asarray(threshold, jnp.float32), state,
-                    jnp.asarray(share, jnp.float32)]
-            if self.pairs:
-                args.append(e_acc)
-            if n_valid is not None:
-                args.append(jnp.asarray(n_valid, jnp.float32))
-            x, info, state = fn(*args)
+            with tracing.span("route.tokenize"):
+                toks = jnp.asarray(tokenizer.encode_batch(
+                    batch.queries, self.predictor.token_len))
+            with tracing.span("route.solve"):
+                fn = self._fused_fn("window", masked=n_valid is not None)
+                args = [self.predictor.device_inputs(), toks,
+                        jnp.asarray(batch.input_len, jnp.float32),
+                        jnp.asarray(batch.price_in, jnp.float32),
+                        jnp.asarray(batch.price_out, jnp.float32),
+                        jnp.asarray(batch.available, jnp.float32),
+                        jnp.asarray(threshold, jnp.float32), state,
+                        jnp.asarray(share, jnp.float32)]
+                if self.pairs:
+                    args.append(e_acc)
+                if n_valid is not None:
+                    args.append(jnp.asarray(n_valid, jnp.float32))
+                x, info, state = fn(*args)
+                x = np.asarray(x)
         else:
-            t0 = time.perf_counter()
-            cap, _, cost = self.predictor.predict_arrays(batch)
-            t1 = time.perf_counter()
-            self.predict_seconds += t1 - t0
-            cost, cap = jnp.asarray(cost), jnp.asarray(cap)
-            if self.pairs:
-                didx, vidx = pair_index_arrays(self.pairs)
-                cost, cap = expand_pair_columns(cost, cap, didx, vidx, e_acc)
-            x, info, state = self.stream_solver.route_window(
-                cost, cap, threshold,
-                jnp.asarray(batch.available), state, share=share,
-                polish_margin=self.cfg.alpha_margin, n_valid=n_valid)
-        x = np.asarray(x)
+            with tracing.span("route.solve"):
+                cap, _, cost = self.predictor.predict_arrays(batch)
+                cost, cap = jnp.asarray(cost), jnp.asarray(cap)
+                if self.pairs:
+                    didx, vidx = pair_index_arrays(self.pairs)
+                    cost, cap = expand_pair_columns(cost, cap, didx, vidx,
+                                                    e_acc)
+                x, info, state = self.stream_solver.route_window(
+                    cost, cap, threshold,
+                    jnp.asarray(batch.available), state, share=share,
+                    polish_margin=self.cfg.alpha_margin, n_valid=n_valid)
+                x = np.asarray(x)
         if _sanitize.active("ledgersan"):
             # the fused jit returns a concrete out-state; the monotone check
             # is the ledger coverage for this path (the solver-level
@@ -328,42 +327,35 @@ class OmniRouter(Policy):
         # to every routing window (SC01); dual_iters sums lazily on read
         self._iters_pending.append(info.iters_run)
         self.windows += 1
-        self.route_seconds += time.perf_counter() - t1
         return x, state
 
     def _route_device(self, batch: RouteBatch) -> np.ndarray:
         """Single-jit path: tokenize on host, everything else on device."""
-        t0 = time.perf_counter()
-        toks = jnp.asarray(tokenizer.encode_batch(
-            batch.queries, self.predictor.token_len))
-        t1 = time.perf_counter()
-        self.predict_seconds += t1 - t0
+        with tracing.span("route.tokenize"):
+            toks = jnp.asarray(tokenizer.encode_batch(
+                batch.queries, self.predictor.token_len))
         threshold, polish_threshold = self._thresholds()
-        x, _ = self._fused_fn("route")(
-            self.predictor.device_inputs(), toks,
-            jnp.asarray(batch.input_len, jnp.float32),
-            jnp.asarray(batch.price_in, jnp.float32),
-            jnp.asarray(batch.price_out, jnp.float32),
-            jnp.asarray(batch.available, jnp.float32),
-            jnp.asarray(threshold, jnp.float32),
-            jnp.asarray(polish_threshold, jnp.float32))
-        x = np.asarray(x)
-        self.route_seconds += time.perf_counter() - t1
-        return x
+        with tracing.span("route.solve"):
+            x, _ = self._fused_fn("route")(
+                self.predictor.device_inputs(), toks,
+                jnp.asarray(batch.input_len, jnp.float32),
+                jnp.asarray(batch.price_in, jnp.float32),
+                jnp.asarray(batch.price_out, jnp.float32),
+                jnp.asarray(batch.available, jnp.float32),
+                jnp.asarray(threshold, jnp.float32),
+                jnp.asarray(polish_threshold, jnp.float32))
+            return np.asarray(x)
 
     def _route_hostpredict(self, batch: RouteBatch) -> np.ndarray:
         """Legacy two-call path for predictors without the device contract."""
-        t0 = time.perf_counter()
-        cap, _, cost = self.predictor.predict_arrays(batch)
-        t1 = time.perf_counter()
-        self.predict_seconds += t1 - t0
         threshold, polish_threshold = self._thresholds()
-        x, _ = self.solver.route_arrays(
-            jnp.asarray(cost), jnp.asarray(cap), threshold,
-            jnp.asarray(batch.available), polish_threshold=polish_threshold)
-        x = np.asarray(x)
-        self.route_seconds += time.perf_counter() - t1
-        return x
+        with tracing.span("route.solve"):
+            cap, _, cost = self.predictor.predict_arrays(batch)
+            x, _ = self.solver.route_arrays(
+                jnp.asarray(cost), jnp.asarray(cap), threshold,
+                jnp.asarray(batch.available),
+                polish_threshold=polish_threshold)
+            return np.asarray(x)
 
 
 def evaluate_assignment(ds: QAServe, x: np.ndarray) -> Dict[str, float]:

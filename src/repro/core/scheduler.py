@@ -32,6 +32,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.common import tracing
 from repro.data import arrivals
 from repro.data.qaserve import QAServe
 from .baselines import Policy
@@ -282,6 +283,9 @@ class _SimExecutor:
     def tick(self):
         self._maybe_hedge()
 
+    def on_release(self, items):
+        pass
+
     # -- internals -------------------------------------------------------------
     def _dispatch(self, qi: int, j: int):
         self._counts[j] += 1
@@ -373,7 +377,9 @@ def run_serving(ds: QAServe, policy: Policy, cfg: SchedulerConfig) -> ServeResul
         items=range(n), features=lambda idx: ds.subset(np.asarray(idx, int)),
         fold=fold, arrival_times=times, window=cfg.window,
         drain_admissions=True, requeue_front=False, health=health)
+    sched0 = tracing.total("route", "fold")
     loop.run()
+    scheduling_seconds = tracing.total("route", "fold") - sched0
     executor.flush_health()
 
     assign = executor.assign
@@ -395,7 +401,7 @@ def run_serving(ds: QAServe, policy: Policy, cfg: SchedulerConfig) -> ServeResul
             pm_cost[j] = cost_mat[idxs[mask], j].sum()
     return ServeResult(
         success_rate=sr, cost=total_cost, makespan=executor.t,
-        scheduling_seconds=controller.route_seconds + fold.fold_seconds,
+        scheduling_seconds=scheduling_seconds,
         llm_seconds=executor.llm_secs,
         per_model_counts=pm_counts, per_model_correct=pm_correct,
         per_model_cost=pm_cost, hedged=executor.hedged,

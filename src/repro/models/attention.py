@@ -265,10 +265,12 @@ def attention_block(
                 # operands, fp32 accumulation) — numerics must not depend on
                 # the cache layout
                 from repro.kernels.decode_attention.ref import gather_pages
-                out = decode_attention_jnp(
-                    q, gather_pages(cache["k_pages"], cache["block_table"]),
-                    gather_pages(cache["v_pages"], cache["block_table"]),
-                    jnp.asarray(pos, jnp.int32) + 1, window=window)
+                with jax.named_scope("gather"):
+                    kd = gather_pages(cache["k_pages"], cache["block_table"])
+                    vd = gather_pages(cache["v_pages"], cache["block_table"])
+                out = decode_attention_jnp(q, kd, vd,
+                                           jnp.asarray(pos, jnp.int32) + 1,
+                                           window=window)
         elif sq > 1:
             out = verify_attention_jnp(q, cache["k"], cache["v"], pos + 1,
                                        window=window)

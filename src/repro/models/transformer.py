@@ -97,10 +97,11 @@ def _ffn_residual(cfg: ModelConfig, kind: LayerKind, params: dict,
     """Shared post-attention tail: ln2 + (MoE or dense) FFN residual — one
     definition so the full-sequence, dense-decode and paged-decode paths
     cannot drift apart."""
-    f = rms_norm(x, params["ln2"], cfg.norm_eps)
-    if kind.is_moe:
-        return x + moe_block(cfg, params["ffn"], f)
-    return x + mlp(params["ffn"], f)
+    with jax.named_scope("mlp"):
+        f = rms_norm(x, params["ln2"], cfg.norm_eps)
+        if kind.is_moe:
+            return x + moe_block(cfg, params["ffn"], f)
+        return x + mlp(params["ffn"], f)
 
 
 def _apply_layer(cfg: ModelConfig, kind: LayerKind, params: dict, x: jax.Array,
@@ -113,10 +114,11 @@ def _apply_layer(cfg: ModelConfig, kind: LayerKind, params: dict, x: jax.Array,
         attn_cache = None
         if cache is not None:
             attn_cache = {"k": cache["k"], "v": cache["v"], "pos": cache["pos"]}
-        a, kv = attention_block(
-            cfg, params["attn"], h, causal=(kind.block != "enc"),
-            window=kind.window, q_offset=q_offset, cache=attn_cache,
-        )
+        with jax.named_scope("attention"):
+            a, kv = attention_block(
+                cfg, params["attn"], h, causal=(kind.block != "enc"),
+                window=kind.window, q_offset=q_offset, cache=attn_cache,
+            )
         x = x + a
         if kv is not None:
             if cfg.kv_cache_dtype == "int8" and kind.block == "attn":
@@ -335,10 +337,12 @@ def _decode_layer_paged(cfg: ModelConfig, kind: LayerKind, params: dict,
                 "block_table": block_table, "pos": lens}
 
     if kind.block == "attn":
-        h = rms_norm(x, params["ln1"], cfg.norm_eps)
-        lc = write_token(h, params["attn"])
-        a, _ = attention_block(cfg, params["attn"], h, causal=True,
-                               window=kind.window, cache=lc, prewritten=True)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, params["ln1"], cfg.norm_eps)
+            lc = write_token(h, params["attn"])
+            a, _ = attention_block(cfg, params["attn"], h, causal=True,
+                                   window=kind.window, cache=lc,
+                                   prewritten=True)
         return _ffn_residual(cfg, kind, params, x + a), ns
     if kind.block == "hymba":
         h = rms_norm(x, params["hymba"]["norm"], cfg.norm_eps)
@@ -613,9 +617,11 @@ class DecoderLM:
             init = (x, seg_state, jnp.zeros((), jnp.int32))
             (x, seg_state, _), _ = jax.lax.scan(body, init, seg_params)
             new_segs.append(list(seg_state))
-        h = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = jnp.einsum("bd,vd->bv", h[:, -1], self._out_table(params),
-                            preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            h = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            logits = jnp.einsum("bd,vd->bv", h[:, -1],
+                                self._out_table(params),
+                                preferred_element_type=jnp.float32)
         return {"segs": new_segs}, logits
 
     # -- paged multi-position verify (speculative cascade) --------------------

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis import sanitize as _sanitize
+from repro.common import tracing
 from repro.common.guards import jit_cache_size as _jit_cache_size
 from repro.configs.base import ModelConfig
 from repro.core.control import (AdmissionRule, ControlLoop, FoldBuffer,
@@ -90,14 +91,18 @@ class _SpecSeq:
 
 @dataclasses.dataclass
 class Request:
+    """One request.  The stage stamps are ``time.perf_counter()`` seconds
+    of the attempt that served it: released into the ready queue, admitted
+    (prefill and page write dispatched), first token emitted, finished."""
     rid: int
     tokens: np.ndarray           # prompt token ids
     max_new: int = 16
-    submitted: float = 0.0
     endpoint: int = -1
     output: Optional[List[int]] = None
     done: bool = False
-    started: float = 0.0
+    released: float = 0.0
+    admitted: float = 0.0
+    first_token: float = 0.0
     finished: float = 0.0
     hedged: bool = False
     admit_step: float = 0.0      # engine clock (decode chunk) at admission
@@ -211,6 +216,9 @@ class Endpoint:
         self._spec_chunks: dict = {}   # draft window k -> jitted k-step chunk
         self.spec_slots: set = set()   # slots driven by the speculative plane
 
+        # the recorder's name for this endpoint: the index MultiLLMServer
+        # gives it (-1: unlabelled)
+        self.label = -1
         self.busy_steps = 0          # chunks dispatched
         self.decoded_tokens = 0      # real (non-masked) tokens emitted
         self.prefill_calls = 0       # one per admitted request
@@ -225,6 +233,7 @@ class Endpoint:
         san = self.alloc.san
         if san is not None:
             san.check_endpoint(self)
+
     def compile_count(self) -> int:
         """Total jit compilations across this endpoint's device functions.
         Constant once every prompt-length bucket has been seen — admissions
@@ -291,34 +300,39 @@ class Endpoint:
             # before any slot/page mutation: the caller gets a clean error
             raise ValueError(f"request {req.rid} needs {plen + req.max_new} "
                              f"positions, endpoint t_max={self.t_max}")
-        req.started = time.perf_counter()
-        req.output = []
-        slot = self.alloc.alloc_slot()
-        if self._has_kv:
-            pages = self.alloc.alloc_pages(
-                pages_per_request(plen, req.max_new, self.page_size))
-            self._slot_pages[slot] = pages
-            self.block_table[slot] = 0
-            self.block_table[slot, :len(pages)] = pages
-        if plen > 0:
-            bucket = self._bucket(plen)
-            ptoks = np.zeros((1, bucket), np.int32)
-            ptoks[0, :plen] = toks[:-1]
-            cache, _ = self._prefill(self.params, jnp.asarray(ptoks))
-            n_prefill_pages = -(-bucket // self.page_size) if self._has_kv else 0
-            page_ids = np.asarray(
-                self._slot_pages[slot][:n_prefill_pages], np.int32)
-            self._state = self._write(self._state, cache,
-                                      jnp.asarray(page_ids),
-                                      jnp.asarray(slot, jnp.int32))
-            self.prefill_calls += 1
-        elif self._has_recurrent:
-            self._state = self._reset(self._state, jnp.asarray(slot, jnp.int32))
-        self.lens[slot] = plen
-        self.remaining[slot] = req.max_new
-        self.last_tokens[slot, 0] = toks[-1]
-        self.slot_req[slot] = req
-        self._san_check()
+        with tracing.span(f"admit.{self.label}", req.rid):
+            req.output = []
+            slot = self.alloc.alloc_slot()
+            if self._has_kv:
+                pages = self.alloc.alloc_pages(
+                    pages_per_request(plen, req.max_new, self.page_size))
+                self._slot_pages[slot] = pages
+                self.block_table[slot] = 0
+                self.block_table[slot, :len(pages)] = pages
+            if plen > 0:
+                bucket = self._bucket(plen)
+                ptoks = np.zeros((1, bucket), np.int32)
+                ptoks[0, :plen] = toks[:-1]
+                with tracing.span(f"admit.prefill.{self.label}"):
+                    cache, _ = self._prefill(self.params, jnp.asarray(ptoks))
+                n_prefill_pages = (-(-bucket // self.page_size)
+                                   if self._has_kv else 0)
+                page_ids = np.asarray(
+                    self._slot_pages[slot][:n_prefill_pages], np.int32)
+                with tracing.span(f"admit.write.{self.label}"):
+                    self._state = self._write(self._state, cache,
+                                              jnp.asarray(page_ids),
+                                              jnp.asarray(slot, jnp.int32))
+                self.prefill_calls += 1
+            elif self._has_recurrent:
+                self._state = self._reset(self._state,
+                                          jnp.asarray(slot, jnp.int32))
+            self.lens[slot] = plen
+            self.remaining[slot] = req.max_new
+            self.last_tokens[slot, 0] = toks[-1]
+            self.slot_req[slot] = req
+            self._san_check()
+        req.admitted = time.perf_counter()
         return slot
 
     # -- fused decode chunk --------------------------------------------------
@@ -334,8 +348,9 @@ class Endpoint:
             state, last, lens, remaining = carry
             state, logits = self.model.decode_step_paged(
                 params, state, last, block_table, lens)
-            nxt = jnp.argmax(logits[:, : self.cfg.vocab_size],
-                             axis=-1).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                nxt = jnp.argmax(logits[:, : self.cfg.vocab_size],
+                                 axis=-1).astype(jnp.int32)
             active = remaining > 0
             nxt = jnp.where(active, nxt, 0)
             lens = lens + active.astype(jnp.int32)
@@ -356,11 +371,12 @@ class Endpoint:
             # every live slot is speculative: the pair rounds drive them,
             # so the frozen chunk would be pure wasted compute
             return None
-        out = self._chunk(self.params, self._state,
-                          jnp.asarray(self.block_table),
-                          jnp.asarray(self.last_tokens),
-                          jnp.asarray(self.lens),
-                          jnp.asarray(self.remaining))
+        with tracing.span(f"chunk.dispatch.{self.label}"):
+            out = self._chunk(self.params, self._state,
+                              jnp.asarray(self.block_table),
+                              jnp.asarray(self.last_tokens),
+                              jnp.asarray(self.lens),
+                              jnp.asarray(self.remaining))
         self._state = out[0]
         self.busy_steps += 1
         return out[1:]
@@ -369,32 +385,42 @@ class Endpoint:
         """Block on the chunk result, distribute tokens, free completions."""
         if pending is None:
             return []
-        last, lens, remaining, toks = (np.array(x) for x in pending)
-        finished = []
-        for slot, req in enumerate(self.slot_req):
-            if req is None or slot in self.spec_slots:
-                # spec slots ride the chunk frozen (remaining 0); the
-                # server's pair rounds emit and complete them
-                continue
-            take = int(min(self.remaining[slot], self.sync_every))
-            req.output.extend(int(t) for t in toks[slot, :take])
-            self.decoded_tokens += take
-            if remaining[slot] == 0:
-                req.done = True
-                req.finished = time.perf_counter()
-                finished.append(req)
-                self.slot_req[slot] = None
-                self.block_table[slot] = 0
-                if self._has_kv:
-                    self.alloc.release_pages(self._slot_pages[slot])
-                    self._slot_pages[slot] = []
-                self.alloc.release_slot(slot)
-                lens[slot] = 0
-                last[slot] = 0
-        self.last_tokens = last
-        self.lens = lens
-        self.remaining = remaining
-        self._san_check()
+        with tracing.span(f"chunk.fetch.{self.label}"):
+            last, lens, remaining, toks = (np.array(x) for x in pending)
+        with tracing.span(f"chunk.emit.{self.label}"):
+            now = time.perf_counter()
+            finished = []
+            emitted = 0
+            for slot, req in enumerate(self.slot_req):
+                if req is None or slot in self.spec_slots:
+                    # spec slots ride the chunk frozen (remaining 0); the
+                    # server's pair rounds emit and complete them
+                    continue
+                take = int(min(self.remaining[slot], self.sync_every))
+                if take and not req.output:
+                    req.first_token = now
+                req.output.extend(int(t) for t in toks[slot, :take])
+                emitted += take
+                if remaining[slot] == 0:
+                    req.done = True
+                    req.finished = now
+                    finished.append(req)
+                    self.slot_req[slot] = None
+                    self.block_table[slot] = 0
+                    if self._has_kv:
+                        self.alloc.release_pages(self._slot_pages[slot])
+                        self._slot_pages[slot] = []
+                    self.alloc.release_slot(slot)
+                    lens[slot] = 0
+                    last[slot] = 0
+            self.last_tokens = last
+            self.lens = lens
+            self.remaining = remaining
+            self._san_check()
+            self.decoded_tokens += emitted
+            # the chunk's growth of the counts member_slot_use reads
+            tracing.count("tokens", self.label, emitted)
+            tracing.count("slot_steps", self.label, self.L * self.sync_every)
         return finished
 
     def step(self) -> List[Request]:
@@ -493,6 +519,7 @@ class Endpoint:
             jnp.asarray(last), jnp.asarray(self.lens), jnp.asarray(rem))
         self._state = out[0]
         self.busy_steps += 1
+        tracing.count("slot_steps", self.label, self.L * k)
         return np.asarray(out[4])
 
     def _verify_fn(self, params, state, tokens, block_table, lens,
@@ -537,6 +564,7 @@ class Endpoint:
                            jnp.asarray(mask), jnp.asarray(rem))
         self._state = out[0]
         self.busy_steps += 1
+        tracing.count("slot_steps", self.label, self.L * k)
         return jax.device_get(out[1:])
 
 
@@ -588,10 +616,10 @@ class RestartEndpoint:
         """Prefill the request and merge into the active batch by restarting
         (re-prefilling) the whole packed batch."""
         assert self.has_capacity()
-        req.started = time.perf_counter()
         req.output = []
         self.active.append(req)
         self._rebuild()
+        req.admitted = time.perf_counter()
 
     def _rebuild(self):
         if not self.active:
@@ -625,11 +653,14 @@ class RestartEndpoint:
         self.decoded_tokens += len(self.active)
         finished = []
         keep = []
+        now = time.perf_counter()
         for i, r in enumerate(self.active):
+            if not r.output:
+                r.first_token = now
             r.output.append(int(nxt[i]))
             if len(r.output) >= r.max_new:
                 r.done = True
-                r.finished = time.perf_counter()
+                r.finished = now
                 finished.append(r)
             else:
                 keep.append(r)
@@ -661,6 +692,11 @@ class _EngineExecutor:
 
     def now(self) -> float:
         return float(self.steps)
+
+    def on_release(self, reqs):
+        t = time.perf_counter()
+        for req in reqs:
+            req.released = t
 
     def loads(self) -> np.ndarray:
         srv = self.server
@@ -800,6 +836,12 @@ class _EngineExecutor:
             # chunk's events makes the health state permutation-invariant
             for j, ok, lat, _ in sorted(events):
                 h.record(j, ok, lat if ok else None, now=float(self.steps))
+        for req in done:
+            if req.first_token:
+                tracing.record("request.queue", req.released, req.admitted,
+                               req.rid)
+                tracing.record("request.first", req.admitted,
+                               req.first_token, req.rid)
         self.server.completed.extend(done)
         return done, progressed
 
@@ -996,6 +1038,8 @@ class _EngineExecutor:
                 primary.output = shadow.output
                 primary.endpoint = shadow.endpoint
                 primary.done = True
+                primary.admitted = shadow.admitted
+                primary.first_token = shadow.first_token
                 primary.finished = shadow.finished
                 out.append(primary)
             else:                       # primary won: kill the shadow
@@ -1027,6 +1071,8 @@ class MultiLLMServer:
                  stall_after_chunks: int = 0, spec_pairs=(),
                  adapt_window=None):
         self.endpoints = endpoints
+        for j, ep in enumerate(endpoints):
+            ep.label = j            # the recorder's name for the endpoint
         self.policy = policy
         cap = sum(e.L for e in endpoints)
         self.rule = AdmissionRule(batch_size).resolve(cap)
@@ -1079,7 +1125,7 @@ class MultiLLMServer:
         self._fold_buf: List[Request] = []   # direct fold-back entry point
         self.folded = 0
         self.route_calls = 0
-        self.route_seconds = 0.0
+        self.route_seconds = 0.0             # in the recorder's route spans
         self.windows = 0
         self.dual_iters = 0
         self.hedged = 0                      # hedge duplicates fired
@@ -1095,7 +1141,6 @@ class MultiLLMServer:
         routed — otherwise the streaming ledger would charge its predicted
         cost/quality for work that is never served and the budget would
         drift (the dual controller's accounting records what was routed)."""
-        req.submitted = time.perf_counter()
         if self.endpoints and not any(
                 getattr(ep, "can_serve", lambda r: True)(req)
                 for ep in self.endpoints):
@@ -1138,52 +1183,59 @@ class MultiLLMServer:
         on the verify endpoint alone.  Rejected draft pages roll back
         through the allocator; live acceptance feeds the router's pair-cost
         EWMAs (AcceptanceTracker — the HealthTracker-style repricing)."""
-        finished: List[Request] = []
-        acc = getattr(self.policy, "acceptance", None)
-        for p, pair in enumerate(self.spec_pairs):
-            seqs = [s for s in self._spec.values() if s.pair == p]
-            if not seqs:
-                continue
-            d_ep = self.endpoints[pair.draft]
-            v_ep = self.endpoints[pair.verify]
-            k = pair.k
-            for s in seqs:
-                d_ep.ensure_pages(s.d_slot, s.base + k)
-                v_ep.ensure_pages(s.v_slot, s.base + k)
-            draft = d_ep.draft_round({s.d_slot: s.pending for s in seqs}, k)
-            v_tokens, v_rem = {}, {}
-            for s in seqs:
-                row = np.empty((k,), np.int32)
-                row[0] = s.pending
-                row[1:] = draft[s.d_slot, : k - 1]
-                v_tokens[s.v_slot] = row
-                v_rem[s.v_slot] = s.remaining
-            strong, n_emit, pending = v_ep.verify_round(v_tokens, v_rem, k)
-            for s in seqs:
-                ne = int(n_emit[s.v_slot])
-                s.req.output.extend(int(t) for t in strong[s.v_slot, :ne])
-                v_ep.decoded_tokens += ne
-                s.base += ne
-                s.remaining -= ne
-                s.pending = int(pending[s.v_slot])
-                d_ep.lens[s.d_slot] = s.base
-                v_ep.lens[s.v_slot] = s.base
-                d_ep.last_tokens[s.d_slot, 0] = s.pending
-                v_ep.last_tokens[s.v_slot, 0] = s.pending
-                d_ep.rollback_pages(s.d_slot, s.base)
-                v_ep.rollback_pages(s.v_slot, s.base)
-                if acc is not None:
-                    acc.record(p, ne)
-                self.spec_rounds += 1
-                self.spec_emitted += ne
-                if s.remaining <= 0:
-                    req = s.req
-                    req.done = True
-                    req.finished = time.perf_counter()
-                    d_ep.release_spec(s.d_slot)
-                    v_ep.release_spec(s.v_slot)
-                    del self._spec[req.rid]
-                    finished.append(req)
+        with tracing.span("spec.round"):
+            finished: List[Request] = []
+            acc = getattr(self.policy, "acceptance", None)
+            for p, pair in enumerate(self.spec_pairs):
+                seqs = [s for s in self._spec.values() if s.pair == p]
+                if not seqs:
+                    continue
+                d_ep = self.endpoints[pair.draft]
+                v_ep = self.endpoints[pair.verify]
+                k = pair.k
+                for s in seqs:
+                    d_ep.ensure_pages(s.d_slot, s.base + k)
+                    v_ep.ensure_pages(s.v_slot, s.base + k)
+                draft = d_ep.draft_round({s.d_slot: s.pending for s in seqs}, k)
+                v_tokens, v_rem = {}, {}
+                for s in seqs:
+                    row = np.empty((k,), np.int32)
+                    row[0] = s.pending
+                    row[1:] = draft[s.d_slot, : k - 1]
+                    v_tokens[s.v_slot] = row
+                    v_rem[s.v_slot] = s.remaining
+                strong, n_emit, pending = v_ep.verify_round(v_tokens, v_rem, k)
+                now = time.perf_counter()
+                emitted = 0
+                for s in seqs:
+                    ne = int(n_emit[s.v_slot])
+                    if ne and not s.req.output:
+                        s.req.first_token = now
+                    s.req.output.extend(int(t) for t in strong[s.v_slot, :ne])
+                    emitted += ne
+                    s.base += ne
+                    s.remaining -= ne
+                    s.pending = int(pending[s.v_slot])
+                    d_ep.lens[s.d_slot] = s.base
+                    v_ep.lens[s.v_slot] = s.base
+                    d_ep.last_tokens[s.d_slot, 0] = s.pending
+                    v_ep.last_tokens[s.v_slot, 0] = s.pending
+                    d_ep.rollback_pages(s.d_slot, s.base)
+                    v_ep.rollback_pages(s.v_slot, s.base)
+                    if acc is not None:
+                        acc.record(p, ne)
+                    self.spec_rounds += 1
+                    self.spec_emitted += ne
+                    if s.remaining <= 0:
+                        req = s.req
+                        req.done = True
+                        req.finished = now
+                        d_ep.release_spec(s.d_slot)
+                        v_ep.release_spec(s.v_slot)
+                        del self._spec[req.rid]
+                        finished.append(req)
+                v_ep.decoded_tokens += emitted
+                tracing.count("tokens", v_ep.label, emitted)
         return finished
 
     def _fold(self, route_features, *, force: bool = False):
@@ -1219,6 +1271,7 @@ class MultiLLMServer:
         times = np.array([t for t, _ in self.queue])
         self.queue.clear()
         executor = self._executor_cls(self, max_steps)
+        route0 = tracing.total("route")
         loop = ControlLoop(
             executor=executor, controller=controller, rule=self.rule,
             items=items, features=route_features, fold=fold,
@@ -1234,8 +1287,7 @@ class MultiLLMServer:
             self.queue.append((0.0, req))
         for at, _, req in loop.pending:
             self.queue.append((max(0.0, at - now), req))
-        self.route_seconds += controller.route_seconds
-        controller.route_seconds = 0.0
+        self.route_seconds += tracing.total("route") - route0
         self.route_calls += controller.windows - windows0
         self.folded += fold.folded
         self.windows += controller.windows - windows0
