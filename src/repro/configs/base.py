@@ -57,7 +57,6 @@ class ModelConfig:
     scan_layers: bool = True
     tie_embeddings: bool = False
     logit_chunk: int = 2048        # chunked-vocab CE: tokens per logit chunk
-    use_pallas: bool = False       # TPU path: Pallas kernels for attention
 
     # hillclimb (EXPERIMENTS.md §Perf iter 5): int8 KV cache with per
     # (token, kv-head) scales — halves decode cache reads (decode is

@@ -1,10 +1,11 @@
-"""Split-KV decode: kernel partials + logsumexp merge (jit wrappers).
+"""Decode attention entry points (jit wrappers).
 
-``decode_attention`` is the dense entry point; ``paged_decode_attention``
-is the serving-plane entry point over a page-pool cache with block-table
-indirection.  Both dispatch by backend: the Pallas kernel on TPU, the jnp
-reference (which gathers pages under XLA) elsewhere — the same pattern as
-``topk_retrieval.ops.retrieval_vote``.
+``decode_attention`` is the dense split-KV kernel plus its logsumexp merge.
+``paged_decode_attention`` / ``paged_verify_attention`` are the serving
+plane's: one launch over the stacked page pools with block-table
+indirection, interpreted off the TPU.  ``reads_pages_in_place`` is the rule
+the model's paged attention selects them by (``models.attention``): the
+backend and the width of a page row.
 """
 from __future__ import annotations
 
@@ -15,6 +16,26 @@ import jax.numpy as jnp
 
 from .kernel import (decode_attention_kernel, paged_decode_attention_kernel,
                      paged_verify_attention_kernel)
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+# The narrowest page row (K·D elements: one position, every KV head) for
+# which the paged kernel beats XLA's gather of the block table on a TPU v5e:
+# at the benchmark's slot geometries the crossover lies between rows of 32
+# and 64 elements, below which a grid step moves too few bytes to pay for
+# itself (PERF.md §6).
+MIN_KERNEL_ROW = 64
+
+
+def reads_pages_in_place(kv_heads: int, head_dim: int) -> bool:
+    """Whether paged attention over pages of ``kv_heads`` x ``head_dim``
+    runs the paged kernel, which reads only the live pages where they lie:
+    on a TPU, for a page row at least ``MIN_KERNEL_ROW`` wide.  Otherwise
+    XLA gathers each slot's block-table row into a dense view."""
+    return on_tpu() and kv_heads * head_dim >= MIN_KERNEL_ROW
 
 
 def merge_partials(o, m, l):
@@ -36,7 +57,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
                      bs: int = 512, interpret: bool | None = None):
     """pos: scalar valid length, or per-sequence (B,) lengths."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     b, _, h, d = q.shape
     o, m, l = decode_attention_kernel(q, k_cache, v_cache, pos,
                                       window=window, bs=bs,
@@ -45,51 +66,28 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
-@partial(jax.jit, static_argnames=("window", "use_kernel"))
-def paged_decode_attention(q, k_pages, v_pages, block_table, lens, *,
-                           window: int = 0, use_kernel: bool | None = None):
-    """q: (B,1,H,D); pools (n_pages, PS, K, D); block_table (B, P) int32;
-    lens (B,) int32 valid lengths.  Returns (B,1,H,D).
-
-    TPU: one Pallas launch with the block table on scalar prefetch (no dense
-    gather).  Off TPU: the jnp reference — XLA lowers the page gather.
-    """
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
-    if not use_kernel:
-        from .ref import paged_decode_attention_ref
-        return paged_decode_attention_ref(q, k_pages, v_pages, block_table,
-                                          lens, window=window)
-    b, _, h, d = q.shape
-    o, m, l = paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
-                                            lens, window=window,
-                                            interpret=False)
-    out = merge_partials(o, m, l)
-    return out.reshape(b, 1, h, d).astype(q.dtype)
+@partial(jax.jit, static_argnames=("window", "interpret"))
+def paged_decode_attention(q, k_pool, v_pool, block_table, lens, layer, *,
+                           window: int = 0, interpret: bool | None = None):
+    """q: (B,1,H,D); stacked pools (L, n_pages, PS, K, D); block_table (B, P)
+    int32; lens (B,) int32 valid lengths; ``layer`` the pools' layer to
+    read.  Returns (B,1,H,D): one Pallas launch that reads the live pages in
+    place (interpreted off the TPU)."""
+    if interpret is None:
+        interpret = not on_tpu()
+    return paged_decode_attention_kernel(q, k_pool, v_pool, block_table, lens,
+                                         layer, window=window,
+                                         interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("window", "use_kernel"))
-def paged_verify_attention(q, k_pages, v_pages, block_table, lens, *,
-                           window: int = 0, use_kernel: bool | None = None):
+@partial(jax.jit, static_argnames=("window", "interpret"))
+def paged_verify_attention(q, k_pool, v_pool, block_table, lens, layer, *,
+                           window: int = 0, interpret: bool | None = None):
     """Speculative verify: q (B,S,H,D) — S query positions per sequence,
     query s of sequence b masked to cache positions < lens[b] + s.
-    Returns (B,S,H,D).
-
-    TPU: one Pallas launch (S folded into the q block rows, block table on
-    scalar prefetch).  Off TPU: the jnp reference.
-    """
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
-    if not use_kernel:
-        from .ref import paged_verify_attention_ref
-        return paged_verify_attention_ref(q, k_pages, v_pages, block_table,
-                                          lens, window=window)
-    b, s_q, h, d = q.shape
-    kh = k_pages.shape[2]
-    g = h // kh
-    o, m, l = paged_verify_attention_kernel(q, k_pages, v_pages, block_table,
-                                            lens, window=window,
-                                            interpret=False)
-    out = merge_partials(o, m, l)                           # (B, K, S·G, D)
-    out = out.reshape(b, kh, s_q, g, d).transpose(0, 2, 1, 3, 4)
-    return out.reshape(b, s_q, h, d).astype(q.dtype)
+    Returns (B,S,H,D); the same launch and body as the decode op."""
+    if interpret is None:
+        interpret = not on_tpu()
+    return paged_verify_attention_kernel(q, k_pool, v_pool, block_table, lens,
+                                         layer, window=window,
+                                         interpret=interpret)

@@ -1,9 +1,10 @@
 """Attention: online-softmax (flash-style) prefill/train path in pure jnp, and
 masked-softmax decode path over a (possibly sequence-sharded) KV cache.
 
-On TPU the Pallas kernels in ``repro.kernels`` replace these bodies
-(``cfg.use_pallas``); the jnp path is the XLA-lowerable reference used by the
-CPU dry-run and the kernels' oracle.
+Paged decode and verify (``paged_attention``) run the paged Pallas kernel on
+a TPU where the page row is wide enough for it, and the jnp decode body over
+a gathered view elsewhere; every other path is jnp, the XLA-lowerable
+reference used by the CPU dry-run and the kernels' oracle.
 """
 from __future__ import annotations
 
@@ -187,6 +188,34 @@ def verify_attention_jnp(
     return out.reshape(b, s_q, h, d).astype(q.dtype)
 
 
+def paged_attention(q: jax.Array, cache: dict, lens: jax.Array, *,
+                    window: int = 0) -> jax.Array:
+    """q (B, S, H, D) — S = 1 decode, S > 1 speculative verify — against
+    the stacked page pools ``cache["k_pages"]`` / ``["v_pages"]`` (L,
+    n_pages, PS, K, D) at layer ``cache["layer"]``, through
+    ``cache["block_table"]`` (B, P); query s of sequence b attends to
+    positions < lens[b] + s.
+
+    Where ``reads_pages_in_place`` holds (a TPU, a wide page row) the paged
+    Pallas kernel reads the live pages in place.  Elsewhere XLA gathers the
+    layer's block-table pages into a dense view and runs the dense decode /
+    verify body (bf16 operands, fp32 accumulation): numerics must not
+    depend on the cache layout."""
+    from repro.kernels.decode_attention import ops
+    verify = q.shape[1] > 1
+    if ops.reads_pages_in_place(*cache["k_pages"].shape[-2:]):
+        fn = ops.paged_verify_attention if verify else ops.paged_decode_attention
+        return fn(q, cache["k_pages"], cache["v_pages"], cache["block_table"],
+                  lens, cache["layer"], window=window, interpret=False)
+    from repro.kernels.decode_attention.ref import gather_pages
+    with jax.named_scope("gather"):
+        kd, vd = (gather_pages(jax.lax.dynamic_index_in_dim(
+            cache[name], cache["layer"], axis=0, keepdims=False),
+            cache["block_table"]) for name in ("k_pages", "v_pages"))
+    body = verify_attention_jnp if verify else decode_attention_jnp
+    return body(q, kd, vd, lens, window=window)
+
+
 def project_kv_token(cfg: ModelConfig, params: dict, x: jax.Array, pos,
                      use_rope: bool = True):
     """K/V projection (+RoPE at pos) for one decode token. x: (B,1,d);
@@ -239,38 +268,8 @@ def attention_block(
             q = rope(q, _pos2d(pos, sq), cfg.rope_theta)
         q = logical_shard(q, "batch", None, None, None)  # gather q heads
         if "k_pages" in cache:  # paged serving plane: block-table indirection
-            if sq > 1:
-                # speculative verify: S prewritten positions per sequence,
-                # one multi-position pass
-                if cfg.use_pallas:
-                    from repro.kernels.decode_attention.ops import paged_verify_attention
-                    out = paged_verify_attention(
-                        q, cache["k_pages"], cache["v_pages"],
-                        cache["block_table"], jnp.asarray(pos, jnp.int32) + 1,
-                        window=window)
-                else:
-                    from repro.kernels.decode_attention.ref import gather_pages
-                    out = verify_attention_jnp(
-                        q, gather_pages(cache["k_pages"], cache["block_table"]),
-                        gather_pages(cache["v_pages"], cache["block_table"]),
-                        jnp.asarray(pos, jnp.int32) + 1, window=window)
-            elif cfg.use_pallas:
-                from repro.kernels.decode_attention.ops import paged_decode_attention
-                out = paged_decode_attention(
-                    q, cache["k_pages"], cache["v_pages"], cache["block_table"],
-                    jnp.asarray(pos, jnp.int32) + 1, window=window)
-            else:
-                # XLA path: gather the block-table pages and run the SAME
-                # mixed-precision body as the dense decode path (bf16
-                # operands, fp32 accumulation) — numerics must not depend on
-                # the cache layout
-                from repro.kernels.decode_attention.ref import gather_pages
-                with jax.named_scope("gather"):
-                    kd = gather_pages(cache["k_pages"], cache["block_table"])
-                    vd = gather_pages(cache["v_pages"], cache["block_table"])
-                out = decode_attention_jnp(q, kd, vd,
-                                           jnp.asarray(pos, jnp.int32) + 1,
-                                           window=window)
+            out = paged_attention(q, cache, jnp.asarray(pos, jnp.int32) + 1,
+                                  window=window)
         elif sq > 1:
             out = verify_attention_jnp(q, cache["k"], cache["v"], pos + 1,
                                        window=window)

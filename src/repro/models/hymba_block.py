@@ -93,7 +93,8 @@ def hymba_layer(cfg: ModelConfig, params: dict, x: jax.Array, *,
     ssm_state = None
     if cache is not None:
         attn_cache = {k: cache[k] for k in
-                      ("k", "v", "k_pages", "v_pages", "block_table", "pos")
+                      ("k", "v", "k_pages", "v_pages", "layer", "block_table",
+                       "pos")
                       if k in cache}
         ssm_state = {"s": cache["s"], "conv": cache["conv"]}
 

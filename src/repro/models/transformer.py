@@ -332,8 +332,7 @@ def _decode_layer_paged(cfg: ModelConfig, kind: LayerKind, params: dict,
                            axis=0).reshape(b, p * page_size, -1)
             return {"k": kd * ksc[..., None].astype(cfg.dtype),
                     "v": vd * vsc[..., None].astype(cfg.dtype), "pos": lens}
-        return {"k_pages": _slice_layer(ns["k"], i),
-                "v_pages": _slice_layer(ns["v"], i),
+        return {"k_pages": ns["k"], "v_pages": ns["v"], "layer": i,
                 "block_table": block_table, "pos": lens}
 
     if kind.block == "attn":
@@ -404,8 +403,7 @@ def _verify_layer_paged(cfg: ModelConfig, kind: LayerKind, params: dict,
                            axis=0).reshape(b, p * page_size, -1)
             return {"k": kd * ksc[..., None].astype(cfg.dtype),
                     "v": vd * vsc[..., None].astype(cfg.dtype), "pos": lens}
-        return {"k_pages": _slice_layer(ns["k"], i),
-                "v_pages": _slice_layer(ns["v"], i),
+        return {"k_pages": ns["k"], "v_pages": ns["v"], "layer": i,
                 "block_table": block_table, "pos": lens}
 
     h = rms_norm(x, params["ln1"], cfg.norm_eps)

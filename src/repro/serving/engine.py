@@ -49,6 +49,7 @@ from repro.common.guards import jit_cache_size as _jit_cache_size
 from repro.configs.base import ModelConfig
 from repro.core.control import (AdmissionRule, ControlLoop, FoldBuffer,
                                 StreamController)
+from repro.kernels.decode_attention.ops import reads_pages_in_place
 from repro.models import build_model
 from repro.models.zoo import (PAGED_POOL_KEYS, pad_cache, pages_per_request,
                               prefill_into_pages, reset_slot)
@@ -190,6 +191,11 @@ class Endpoint:
                        for k in layer}
         self._has_kv = "k" in leaves_keys
         self._has_recurrent = bool(leaves_keys - set(PAGED_POOL_KEYS))
+        # decode attention reads only the live pages, where they lie (the
+        # paged kernel; an int8 pool is dequantized from a gathered view)
+        self.reads_in_place = (self._has_kv and "k_scale" not in leaves_keys
+                               and reads_pages_in_place(cfg.n_kv_heads,
+                                                        cfg.hd))
         # worst case: every slot at t_max, +1 for the dump page
         n_pages = 1 + self.L * self.pages_per_slot if self._has_kv else 1
         self.alloc = PageAllocator(n_pages, self.L)
@@ -362,7 +368,9 @@ class Endpoint:
         return state, last, lens, remaining, toks.T   # toks: (B, length)
 
     def step_begin(self):
-        """Dispatch one decode chunk (async) — does not block."""
+        """Dispatch one decode chunk (async) — does not block.  Returns its
+        pending results and, on the kernel path, the KV pages it attends
+        to (None elsewhere)."""
         if self.active_count() == 0:
             return None
         if self.spec_slots and all(
@@ -379,14 +387,26 @@ class Endpoint:
                               jnp.asarray(self.remaining))
         self._state = out[0]
         self.busy_steps += 1
-        return out[1:]
+        return out[1:], self._chunk_kv_pages() if self.reads_in_place else None
+
+    def _chunk_kv_pages(self) -> int:
+        """KV pages the chunk dispatched from the host mirrors attends to:
+        each occupied slot, at each step, reads the pages of its length
+        plus the step's token (a slot that finishes inside the chunk stays
+        at its last length)."""
+        live = np.array([r is not None for r in self.slot_req])
+        steps = np.arange(self.sync_every)
+        seen = (self.lens[live, None] + 1
+                + np.minimum(steps, self.remaining[live, None]))
+        return int((-(-seen // self.page_size)).sum())
 
     def step_end(self, pending) -> List[Request]:
         """Block on the chunk result, distribute tokens, free completions."""
         if pending is None:
             return []
+        results, kv_pages = pending
         with tracing.span(f"chunk.fetch.{self.label}"):
-            last, lens, remaining, toks = (np.array(x) for x in pending)
+            last, lens, remaining, toks = (np.array(x) for x in results)
         with tracing.span(f"chunk.emit.{self.label}"):
             now = time.perf_counter()
             finished = []
@@ -421,6 +441,12 @@ class Endpoint:
             # the chunk's growth of the counts member_slot_use reads
             tracing.count("tokens", self.label, emitted)
             tracing.count("slot_steps", self.label, self.L * self.sync_every)
+            if kv_pages is not None:
+                # the pages the chunk's attention read in place, and what
+                # reading every slot's whole block-table row would read
+                tracing.count("kv_pages", self.label, kv_pages)
+                tracing.count("kv_pages_table", self.label,
+                              self.L * self.pages_per_slot * self.sync_every)
         return finished
 
     def step(self) -> List[Request]:
