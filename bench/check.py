@@ -5,10 +5,11 @@ finished.
 * ``logit_gap`` — once the window has closed and the program's state is
   freed: a seed-drawn sample of the requests the full-width member
   finished, the longest among them, packed into one ``PACK``-position row
-  (prompt + served tokens).  The fp32 reference runs once over the row; at
-  every served position the gap is the reference's best logit less the
-  logit of the token the server emitted.  The number compared is the widest
-  gap.  Greedy tokens only: the engine samples by argmax.
+  (prompt + served tokens).  The fp32 reference of the member's family
+  (``bench.families``) runs once over the row; at every served position
+  the gap is the reference's best logit less the logit of the token the
+  server emitted.  The number compared is the widest gap.  Greedy tokens
+  only: the engine samples by argmax.
 * ``over_capacity`` — requests a routed window assigned to an endpoint
   beyond its free slots, summed over windows; and ``bad_column``, routed
   columns outside the pool.  Both exact: limit 0.
@@ -24,7 +25,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from bench import reference, weights
+from bench import families, weights
 
 PACK = 2048       # positions of the packed reference row
 
@@ -85,7 +86,7 @@ def logit_gap(cfg: dict, seed: int, served: List[Served]) -> Dict:
         return {"logit_gap": float("inf"), "tokens_compared": 0}
     tokens, seg, pos, targets = packed
     w = weights.make(cfg, seed)
-    lg = np.asarray(reference.logits(cfg, w, tokens, seg, pos))
+    lg = np.asarray(families.load(cfg).logits(cfg, w, tokens, seg, pos))
     g = gaps(lg, targets)
     return {"logit_gap": float(g.max()), "tokens_compared": int(len(g))}
 
@@ -96,8 +97,9 @@ def control_gap(cfg: dict, seed: int, served: List[Served]) -> Dict:
     first."""
     tokens, seg, pos, targets = pack(served, seed)
     w = weights.make(cfg, seed)
-    ref = np.asarray(reference.logits(cfg, w, tokens, seg, pos))
-    low = np.asarray(reference.logits(cfg, w, tokens, seg, pos, lowp=True))
+    logits = families.load(cfg).logits
+    ref = np.asarray(logits(cfg, w, tokens, seg, pos))
+    low = np.asarray(logits(cfg, w, tokens, seg, pos, lowp=True))
     rows = targets >= 0
     pick = np.where(rows, low.argmax(-1), -1)
     g = gaps(ref, pick)

@@ -1,6 +1,5 @@
 """Model FLOPs of the tokens the full-width member decoded, over its
 decode chunks' device time at the chip's bf16 peak."""
-from bench import flops
 
 
 def read(ctx):
@@ -8,5 +7,6 @@ def read(ctx):
     chunks = [c for c in ctx.win.chunks if len(c[0])]
     if not n or not chunks:
         return None
-    work = sum(flops.decode_chunk(ctx.s, *c)[0] for c in chunks) / len(chunks)
+    work = sum(ctx.family.decode_chunk(ctx, *c)[0]
+               for c in chunks) / len(chunks)
     return 100.0 * work / ((t / n) * ctx.peaks["bf16_flops"])

@@ -2,7 +2,6 @@
 decode chunks: the least bytes its steps need (weights once per step, the
 live keys and values of active slots) at peak HBM bandwidth, over the
 chunks' device time."""
-from bench import flops
 
 
 def read(ctx):
@@ -10,5 +9,6 @@ def read(ctx):
     chunks = [c for c in ctx.win.chunks if len(c[0])]
     if not n or not chunks:
         return None
-    least = sum(flops.decode_chunk(ctx.s, *c)[1] for c in chunks) / len(chunks)
+    least = sum(ctx.family.decode_chunk(ctx, *c)[1]
+                for c in chunks) / len(chunks)
     return 100.0 * (least / ctx.peaks["hbm_bytes_per_s"]) / (t / n)

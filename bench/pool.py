@@ -2,7 +2,8 @@
 ``Endpoint`` per pool column behind a streaming
 ``OmniRouter(RetrievalPredictor)`` in a ``MultiLLMServer`` — what
 ``repro.launch.serve.build_server`` builds, with each endpoint seeded from
-the run seed and the full-width member's weights from ``bench.weights``.
+the run seed and the full-width member's weights from ``bench.weights``,
+laid out by its family (``bench.families``).
 """
 from __future__ import annotations
 
@@ -12,15 +13,8 @@ import gc
 import jax
 import numpy as np
 
+from bench import families
 from bench import weights as W
-
-# configuration-file key -> ModelConfig field of the full-width member
-_FIELDS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-           "intermediate_size": "d_ff", "num_attention_heads": "n_heads",
-           "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-           "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-           "sliding_window": "sliding_window", "rms_norm_eps": "norm_eps",
-           "tie_word_embeddings": "tie_embeddings"}
 
 
 def model_configs(cfg: dict, *, smoke_main: bool = False):
@@ -28,9 +22,10 @@ def model_configs(cfg: dict, *, smoke_main: bool = False):
     every number of the configuration file; ``smoke_main`` serves the
     program's smoke config in its place (CPU rehearsal)."""
     from repro.configs import get_config, get_smoke_config
+    fields = families.load(cfg).FIELDS
     main = get_smoke_config(cfg["arch"]) if smoke_main else \
         dataclasses.replace(get_config(cfg["arch"]),
-                            **{f: cfg[k] for k, f in _FIELDS.items()})
+                            **{f: cfg[k] for k, f in fields.items()})
     cols = {c["column"]: get_smoke_config(c["arch"])
             for c in cfg["stand_in_columns"]}
     cols[cfg["main_column"]] = main
@@ -39,23 +34,9 @@ def model_configs(cfg: dict, *, smoke_main: bool = False):
 
 def main_sizes(cfg: dict, mcfg) -> dict:
     """The numbers ``bench.weights`` and the reference take, read back from
-    the ModelConfig actually served (smoke or full)."""
-    return dict(cfg, num_hidden_layers=mcfg.n_layers, hidden_size=mcfg.d_model,
-                intermediate_size=mcfg.d_ff,
-                num_attention_heads=mcfg.n_heads,
-                num_key_value_heads=mcfg.n_kv_heads, head_dim=mcfg.hd,
-                vocab_size=mcfg.vocab_size, rope_theta=mcfg.rope_theta,
-                sliding_window=mcfg.sliding_window, rms_norm_eps=mcfg.norm_eps)
-
-
-def program_tree(w: dict) -> dict:
-    """The reference layout in the program's parameter tree (one scanned
-    segment of identical attention layers)."""
-    layer = {"attn": {k: w[k] for k in ("wq", "wk", "wv", "wo")},
-             "ffn": {k: w[k] for k in ("w_gate", "w_up", "w_down")},
-             "ln1": w["ln1"], "ln2": w["ln2"]}
-    return {"embed": w["embed"], "final_norm": w["final_norm"],
-            "out_embed": w["out_embed"], "segs": [[layer]]}
+    the ModelConfig actually served (smoke or full), by the member's
+    family."""
+    return families.load(cfg).main_sizes(cfg, mcfg)
 
 
 def seeded(seed: int, column: int) -> int:
@@ -77,7 +58,8 @@ def build(cfg: dict, cfgs, *, seed: int, history):
             want = jax.tree.map(lambda a: (a.shape, a.dtype), ep.params)
             ep.params = None            # free the program's draw first
             gc.collect()
-            tree = program_tree(W.make(main_sizes(cfg, mc), seed))
+            tree = families.load(cfg).program_tree(
+                W.make(main_sizes(cfg, mc), seed), mc)
             got = jax.tree.map(lambda a: (a.shape, a.dtype), tree)
             if got != want:
                 raise ValueError("bench.weights does not match the served "

@@ -320,8 +320,9 @@ def main(argv=None):
     served = cell.served(sched, done)
     trace_ctx = None
     if args.trace:
-        trace_ctx = TraceContext(trace_dir, win,
-                                 cell.msizes, devs[0].device_kind,
+        from bench import trace
+        trace_ctx = TraceContext(trace.load(trace.find_xplane(trace_dir)),
+                                 win, cell.msizes, devs[0].device_kind,
                                  cell.endpoints[cell.main].sync_every)
         shutil.rmtree(trace_dir, ignore_errors=True)
     limits = cell.params["limits"]
@@ -396,13 +397,13 @@ def chip(chips: int, *, rehearse: bool):
 
 
 class TraceContext:
-    """What a per-layer reader gets: the reduced trace and the run's own
-    records."""
+    """What a per-layer reader gets: the reduced trace, the run's own
+    records, and the full-width member's family (``family``, its ``sizes``
+    as ``s``) for the work counts."""
 
-    def __init__(self, trace_dir, win, msizes, kind, steps_per_chunk):
-        from bench import peaks, trace
-        from bench import weights as W
-        self.events = trace.load(trace.find_xplane(trace_dir))
+    def __init__(self, events, win, msizes, kind, steps_per_chunk):
+        from bench import families, peaks, trace
+        self.events = events
         self.lo, self.hi = trace.window(self.events)
         self.window_s = self.hi - self.lo
         self.busy_s = float(np.mean([trace.device_busy(self.events, self.lo,
@@ -410,12 +411,13 @@ class TraceContext:
                                      for d in trace.devices(self.events)]
                                     or [0.0]))
         self.win = win
-        self.s = W.sizes(msizes)
+        self.family = families.load(msizes)
+        self.s = self.family.sizes(msizes)
         self.peaks = peaks.peaks(kind)
-        from bench import flops
-        # a step of the full-width member reads all its weights at least
-        # once: no program of it can run faster than that at peak bandwidth
-        step = flops.weight_bytes(self.s) / self.peaks["hbm_bytes_per_s"]
+        # no program of the full-width member can run a step faster than
+        # its family's least bytes a step take at peak bandwidth
+        step = self.family.step_floor_bytes(self) / self.peaks[
+            "hbm_bytes_per_s"]
         self._floor = {"jit__chunk_fn": steps_per_chunk * step,
                        "jit_prefill": step}
         self._trace = trace

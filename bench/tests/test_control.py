@@ -1,19 +1,20 @@
 """The control: the plain reference at float8, in the program's place,
 must read as not correct where the served program reads as correct.
 
-At a size a test run holds: a dense GQA member of 4 layers at width 256,
-served by the program's paged ``Endpoint`` (bf16) with ``bench.weights``,
-and compared with the fp32 reference as ``bench.check`` does on the chip.
+At a size a test run holds: a dense GQA member (``bench.families.dense_gqa``)
+of 4 layers at width 256, served by the program's paged ``Endpoint`` (bf16)
+with ``bench.weights`` laid out by its family, and compared with the fp32
+reference as ``bench.check`` does on the chip.
 The chip readings at the cells' own sizes are in ``PERF.md``.
 """
 import numpy as np
 import pytest
 
-from bench import check, pool
+from bench import check, families
 from bench import weights as W
 
-SMALL = {"arch": "h2o-danube-3-4b", "num_hidden_layers": 4,
-         "hidden_size": 256, "intermediate_size": 768,
+SMALL = {"arch": "h2o-danube-3-4b", "family": "dense_gqa",
+         "num_hidden_layers": 4, "hidden_size": 256, "intermediate_size": 768,
          "num_attention_heads": 8, "num_key_value_heads": 2, "head_dim": 32,
          "vocab_size": 2048, "rope_theta": 10000.0, "sliding_window": 0,
          "rms_norm_eps": 1e-5, "tie_word_embeddings": False}
@@ -26,10 +27,11 @@ def serve_small(seed: int, n_req: int = 4, max_new: int = 24):
 
     from repro.configs import get_config
     from repro.serving.engine import Endpoint, Request
+    fam = families.load(SMALL)
     mcfg = dataclasses.replace(get_config(SMALL["arch"]), **{
-        f: SMALL[k] for k, f in pool._FIELDS.items()})
+        f: SMALL[k] for k, f in fam.FIELDS.items()})
     ep = Endpoint(mcfg, max_concurrency=n_req, t_max=128, seed=seed)
-    ep.params = pool.program_tree(W.make(SMALL, seed))
+    ep.params = fam.program_tree(W.make(SMALL, seed), mcfg)
     rs = np.random.RandomState(seed)
     reqs = [Request(rid=i, tokens=rs.randint(2, SMALL["vocab_size"],
                                              size=int(rs.randint(20, 60))

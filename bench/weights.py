@@ -3,12 +3,8 @@
 One jitted call draws every leaf on the device in bf16, the type it is
 served in.  Both the served endpoint and the plain reference take their
 weights from here, each calling it with the seed; neither reads the
-other's copy.
-
-The scales give unit-variance pre-activations (fan-in scaling on every
-projection), as a trained model roughly has.  Random weights at the
-program's own init scales saturate attention (q·k logits of order 100), and
-such a model's greedy tokens flip on rounding alone.
+other's copy.  The leaves, their shapes and scales are the member's
+family's (``bench.families``).
 """
 from __future__ import annotations
 
@@ -18,36 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-def sizes(cfg: dict) -> dict:
-    """The shape numbers of a configuration file, by short name."""
-    d = int(cfg["hidden_size"])
-    h = int(cfg["num_attention_heads"])
-    return dict(L=int(cfg["num_hidden_layers"]), d=d, H=h,
-                K=int(cfg["num_key_value_heads"]),
-                D=int(cfg.get("head_dim") or d // h),
-                F=int(cfg["intermediate_size"]), V=int(cfg["vocab_size"]),
-                Vp=-(-int(cfg["vocab_size"]) // 256) * 256)
-
-
-def shapes(s: dict) -> dict:
-    L, d, H, K, D, F, Vp = (s[k] for k in ("L", "d", "H", "K", "D", "F",
-                                           "Vp"))
-    return {"embed": (Vp, d), "out_embed": (Vp, d), "final_norm": (d,),
-            "ln1": (L, d), "ln2": (L, d),
-            "wq": (L, d, H, D), "wk": (L, d, K, D), "wv": (L, d, K, D),
-            "wo": (L, H, D, d),
-            "w_gate": (L, d, F), "w_up": (L, d, F), "w_down": (L, F, d)}
-
-
-def _std(name: str, s: dict) -> float:
-    if name == "embed":
-        return 1.0
-    if name == "wo":
-        return float(1.0 / np.sqrt(s["H"] * s["D"]))
-    if name == "w_down":
-        return float(1.0 / np.sqrt(s["F"]))
-    return float(1.0 / np.sqrt(s["d"]))     # wq wk wv w_gate w_up out_embed
+from bench import families
 
 
 def seed_key(seed: int, purpose: int) -> jax.Array:
@@ -58,20 +25,25 @@ def seed_key(seed: int, purpose: int) -> jax.Array:
     return jax.random.PRNGKey(int(word))
 
 
-@functools.partial(jax.jit, static_argnames=("frozen",))
-def _draw(key, frozen):
-    s = dict(frozen)
+@functools.partial(jax.jit, static_argnames=("leaves",))
+def _draw(key, leaves):
     out = {}
-    for i, (name, shape) in enumerate(sorted(shapes(s).items())):
-        if name in ("final_norm", "ln1", "ln2"):
+    for i, (name, shape, std) in enumerate(leaves):
+        if std is None:
             out[name] = jnp.ones(shape, jnp.bfloat16)
             continue
         k = jax.random.fold_in(key, i)
-        out[name] = (_std(name, s) * jax.random.normal(k, shape, jnp.float32)
+        out[name] = (std * jax.random.normal(k, shape, jnp.float32)
                      ).astype(jnp.bfloat16)
     return out
 
 
 def make(cfg: dict, seed: int) -> dict:
-    """bf16 weights of the configuration's full-width member for ``seed``."""
-    return _draw(seed_key(seed, 7), tuple(sorted(sizes(cfg).items())))
+    """bf16 weights of the configuration's full-width member for ``seed``:
+    its family's leaves, in sorted order, each from the seed's key folded
+    with its index."""
+    fam = families.load(cfg)
+    s = fam.sizes(cfg)
+    leaves = tuple((name, tuple(shape), fam.scale(name, s))
+                   for name, shape in sorted(fam.shapes(s).items()))
+    return _draw(seed_key(seed, 7), leaves)
